@@ -5,7 +5,8 @@ For each fault rate in RATES a seeded :class:`repro.api.FaultPlan`
 injects ``serve.settle`` faults (the deferred-device-error shape under
 JAX async dispatch) into a warm serving pass, and the benchmark records
 what the retry ladder COSTS: warm whole-stream wall clock, recovered
-throughput (states/s — every request still completes, bit-exact), p50/
+throughput (states/s — every request still completes, within
+``REORDER_TOL`` of the fault-free result), p50/
 p95 submit->result latency, and the fault counters (injected faults,
 bucket failures, retries).  Rate 0.0 is the fault-free reference row, so
 ``degradation_x`` is directly the chaos tax.
@@ -20,10 +21,11 @@ stories the runtime supports.  The retry backoff is deliberately small
 The ``--mesh`` section (also part of ``--json``) prices the DISTRIBUTED
 rung: a mesh-sharded rollout hit by a seeded ``dist.exchange`` fault
 storm that exhausts a segment's retry budget and forces a 4 -> 2
-reshard-on-failure from the shard checkpoint.  It runs in a subprocess
-with 8 fake CPU devices (the bench process itself stays at 1 device)
+reshard-on-failure from the shard checkpoint.  It runs in this process
+on its first 4 devices (four chips, or on the CPU 8 host-platform devices,
+which this script requests before JAX starts)
 and records the reshard-recovery tax — faulted wall clock over the
-fault-free mesh run, recovery bit-exact.
+fault-free mesh run, recovery within ``REORDER_TOL``.
 
     PYTHONPATH=src python benchmarks/bench_chaos.py --json [--out BENCH_chaos.json]
     PYTHONPATH=src python benchmarks/bench_chaos.py          # readable table
@@ -36,13 +38,18 @@ diffable recovery-cost trajectory point in ``BENCH_chaos.json``.
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
-import numpy as np
+# host-platform devices for the mesh row when this runs on the CPU (no
+# effect on a TPU); must be set before JAX starts
+os.environ.setdefault("XLA_FLAGS",
+                      "--xla_force_host_platform_device_count=8")
 
-from repro import api
+import numpy as np  # noqa: E402
+
+from repro import api  # noqa: E402
+from repro.kernels.ref import REORDER_TOL  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 BENCH_VERSION = 2
 
@@ -96,7 +103,7 @@ def _run_background(server, states, rate):
 
 def measure(rates=RATES, requests=REQUESTS):
     """One warm measured pass per (mode, rate); every row's results are
-    checked bit-identical to the fault-free sync baseline."""
+    checked against the fault-free sync baseline within REORDER_TOL."""
     rng = np.random.default_rng(3)
     states = [rng.normal(size=GRID).astype(np.float32)
               for _ in range(requests)]
@@ -119,7 +126,9 @@ def measure(rates=RATES, requests=REQUESTS):
             if baseline is None:
                 baseline = arr             # sync rate-0 reference
             for a, b in zip(arr, baseline):
-                np.testing.assert_array_equal(a, b)   # recovery is exact
+                # recovery recomputes the bucket, maybe at another bucket
+                # size: equal to the baseline up to contraction order
+                np.testing.assert_allclose(a, b, **REORDER_TOL)
             s = server.stats()
             rows[f"{rate:g}"] = {
                 "wall_ms": wall * 1e3,
@@ -137,89 +146,90 @@ def measure(rates=RATES, requests=REQUESTS):
     return out
 
 
-# The distributed rung: measured in a child process with fake devices.
-_MESH_DEVICES = 8
-_MESH_CHILD = r"""
-import json, tempfile, time
-import numpy as np, jax.numpy as jnp
-from repro import api
-from repro.launch.mesh import make_mesh
-from repro.rollout.program import RolloutProgram, Segment, UpdateOp
-from repro.rollout.executor import compile_program, run_checkpointed
-
-SPEC = api.PAPER_SUITE()["box2d_r1"]
-GRID = (48, 48)
-X = jnp.asarray(np.random.default_rng(0).normal(size=GRID), jnp.float32)
-
-def compiled(n):
-    prob = api.StencilProblem(SPEC, GRID, boundary="periodic", steps=1,
-                              mesh=make_mesh((n,), ("gx",)),
-                              grid_axes=("gx", ""))
-    prog = RolloutProgram(prob, [
-        Segment(2, emit=True),
-        Segment(2, UpdateOp("scale", {"factor": 0.5}), emit=True),
-        Segment(2, emit=True)])
-    return compile_program(prog, backends=["jnp"])
-
-def timed(fn, reps=3):
-    best, out = None, None
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = fn()
-        dt = time.perf_counter() - t0
-        best = dt if best is None else min(best, dt)
-    return best, out
-
-def checkpointed(n):
-    # fault-free rows checkpoint too, so the tax ratio isolates the
-    # retries + reshard recompile + resharded restore, not the writes
-    with tempfile.TemporaryDirectory() as d:
-        return run_checkpointed(compiled(n), X, directory=d)
-
-compiled(4); compiled(2)                      # warm the compiles
-free_s, ref = timed(lambda: checkpointed(4))
-shrunk_s, _ = timed(lambda: checkpointed(2))
-
-def faulted():
-    with tempfile.TemporaryDirectory() as d:
-        plan = api.FaultPlan(seed=5).rule("dist.exchange", at=(1, 2, 3),
-                                          match={"chunk": 0})
-        with plan:
-            res = run_checkpointed(
-                compiled(4), X, directory=d,
-                restart=api.RestartPolicy(max_failures=2, backoff_s=0.0))
-        return plan, res
-
-fault_s, (plan, res) = timed(faulted)
-assert res.resharded == 1 and res.recovered == (0, 1, 0), (
-    res.resharded, res.recovered)
-for (_, a), (_, b) in zip(res.emits, ref.emits):
-    assert np.array_equal(np.asarray(a), np.asarray(b)), "not bit-exact"
-print(json.dumps({
-    "mesh_shape": [4], "shrunk_shape": [2], "grid": list(GRID),
-    "site": "dist.exchange", "injected": plan.fired(),
-    "attempts": list(res.attempts), "resharded": res.resharded,
-    "fault_free_ms": free_s * 1e3,
-    "shrunk_fault_free_ms": shrunk_s * 1e3,
-    "faulted_ms": fault_s * 1e3,
-    "reshard_tax_x": fault_s / free_s,
-    "bit_exact": True,
-}))
-"""
+# The distributed rung: a 4-device mesh of this process's own devices (four
+# chips, or XLA's host-platform devices on the CPU).  It runs in this
+# process: a parent that has touched JAX holds the chip, so a child could
+# not reach it.
+_MESH_DEVICES = 4
 
 
 def measure_mesh():
-    """The reshard-recovery tax row, measured under fake devices."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_MESH_DEVICES}"
-    env.setdefault("PYTHONPATH",
-                   os.path.join(os.path.dirname(__file__), "..", "src"))
-    proc = subprocess.run([sys.executable, "-c", _MESH_CHILD],
-                          capture_output=True, text=True, env=env,
-                          timeout=600)
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh bench child failed:\n{proc.stderr}")
-    return json.loads(proc.stdout.splitlines()[-1])
+    """The reshard-recovery tax row: a seeded ``dist.exchange`` storm on a
+    4-way mesh forces a 4 -> 2 reshard; recovery within REORDER_TOL of
+    the fault-free 4-device run."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
+    from repro.rollout.executor import compile_program, run_checkpointed
+    from repro.rollout.program import RolloutProgram, Segment, UpdateOp
+
+    devices = jax.devices()
+    if len(devices) < _MESH_DEVICES:
+        raise RuntimeError(
+            f"the mesh row needs {_MESH_DEVICES} devices, this process has "
+            f"{len(devices)} (on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={_MESH_DEVICES})")
+    spec = api.PAPER_SUITE()[CELL]
+    x = jnp.asarray(np.random.default_rng(0).normal(size=GRID), jnp.float32)
+
+    def compiled(n):
+        prob = api.StencilProblem(spec, GRID, boundary="periodic", steps=1,
+                                  mesh=make_mesh((n,), ("gx",),
+                                                 devices=devices[:n]),
+                                  grid_axes=("gx", ""))
+        prog = RolloutProgram(prob, [
+            Segment(2, emit=True),
+            Segment(2, UpdateOp("scale", {"factor": 0.5}), emit=True),
+            Segment(2, emit=True)])
+        return compile_program(prog, backends=["jnp"])
+
+    def timed(fn, reps=3):
+        best, out = None, None
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            out = fn()
+            dt = time.perf_counter() - t0
+            best = dt if best is None else min(best, dt)
+        return best, out
+
+    def checkpointed(n):
+        # fault-free rows checkpoint too, so the tax ratio isolates the
+        # retries + reshard recompile + resharded restore, not the writes
+        with tempfile.TemporaryDirectory() as d:
+            return run_checkpointed(compiled(n), x, directory=d)
+
+    compiled(4), compiled(2)                  # warm the compiles
+    free_s, ref = timed(lambda: checkpointed(4))
+    shrunk_s, _ = timed(lambda: checkpointed(2))
+
+    def faulted():
+        with tempfile.TemporaryDirectory() as d:
+            plan = api.FaultPlan(seed=5).rule("dist.exchange", at=(1, 2, 3),
+                                              match={"chunk": 0})
+            with plan:
+                res = run_checkpointed(
+                    compiled(4), x, directory=d,
+                    restart=api.RestartPolicy(max_failures=2, backoff_s=0.0))
+            return plan, res
+
+    fault_s, (plan, res) = timed(faulted)
+    assert res.resharded == 1 and res.recovered == (0, 1, 0), (
+        res.resharded, res.recovered)
+    for (_, a), (_, b) in zip(res.emits, ref.emits):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   **REORDER_TOL)
+    return {
+        "mesh_shape": [4], "shrunk_shape": [2], "grid": list(GRID),
+        "site": "dist.exchange", "injected": plan.fired(),
+        "attempts": list(res.attempts), "resharded": res.resharded,
+        "fault_free_ms": free_s * 1e3,
+        "shrunk_fault_free_ms": shrunk_s * 1e3,
+        "faulted_ms": fault_s * 1e3,
+        "reshard_tax_x": fault_s / free_s,
+        "within_tol": True,
+    }
 
 
 def emit_json(path="BENCH_chaos.json"):
@@ -239,7 +249,7 @@ def emit_json(path="BENCH_chaos.json"):
     worst = max(r["degradation_x"] for rows in m.values()
                 for r in rows.values())
     print(f"wrote {path}: {len(RATES)} fault rates x "
-          f"{len(m)} modes, all recoveries bit-exact; worst-case "
+          f"{len(m)} modes, all recoveries within tolerance; worst-case "
           f"chaos tax {worst:.2f}x wall clock; mesh reshard tax "
           f"{data['mesh']['reshard_tax_x']:.2f}x")
     return data
@@ -257,7 +267,8 @@ def table():
 
 
 def smoke():
-    """Tiny tier-1 pass: one faulted rate per mode, recovery bit-exact."""
+    """Tiny tier-1 pass: one faulted rate per mode, recovery within
+    REORDER_TOL of the fault-free pass."""
     m = measure(rates=(0.0, 0.3), requests=6)
     for mode in ("sync", "background"):
         faulted = m[mode]["0.3"]
@@ -266,7 +277,7 @@ def smoke():
         print(f"{mode}: rate 0.3 -> {faulted['injected']} faults, "
               f"{faulted['retries']} retries, "
               f"{faulted['throughput_states_per_s']:.1f} states/s "
-              f"(tax {faulted['degradation_x']:.2f}x), all bit-exact")
+              f"(tax {faulted['degradation_x']:.2f}x), all recovered")
     print("bench-chaos smoke OK")
 
 
@@ -279,8 +290,9 @@ def main():
                     help="tiny faulted pass per mode (the tier-1 gate)")
     ap.add_argument("--mesh", action="store_true",
                     help="only the distributed reshard-recovery tax row "
-                         "(subprocess with fake devices)")
+                         "(needs 4 devices in this process)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         smoke()
         return
@@ -288,7 +300,7 @@ def main():
         row = measure_mesh()
         print(json.dumps(row, indent=2, sort_keys=True))
         print(f"reshard 4 -> 2 recovery tax "
-              f"{row['reshard_tax_x']:.2f}x (bit-exact)")
+              f"{row['reshard_tax_x']:.2f}x (within tolerance)")
         return
     if args.json:
         emit_json(args.out)

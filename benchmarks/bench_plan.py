@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro import api
 from repro.core.engine import StencilEngine
 from repro.launch.calibrate import calibrate_suite
+from repro.launch.compile_cache import enable_compile_cache
 
 # the plan-report cells (launch.plan_report): one shape-preserving
 # evolution per paper spec
@@ -159,6 +160,7 @@ def emit_json(path="BENCH_plan.json", steps=MODEL_STEPS,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", action="store_true",
                     help="emit the machine-readable BENCH_plan.json "

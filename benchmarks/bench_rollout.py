@@ -31,6 +31,7 @@ import time
 import numpy as np
 
 from repro import api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rollout.program import build_update
 
 BENCH_VERSION = 1
@@ -196,6 +197,7 @@ def smoke():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", action="store_true",
                     help="emit the machine-readable BENCH_rollout.json")

@@ -39,6 +39,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro import api
+from repro.launch.compile_cache import enable_compile_cache
 
 MODEL_GRID_2D = (256, 256)
 MODEL_GRID_3D = (64, 64, 64)
@@ -190,6 +191,7 @@ def smoke():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", action="store_true",
                     help="emit the machine-readable BENCH_serve.json")

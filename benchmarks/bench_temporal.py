@@ -24,6 +24,7 @@ from repro.core import stencil_spec as ss
 from repro.core.engine import StencilEngine
 from repro.core.temporal import (FUSE_STRATEGIES, choose_fuse_depth,
                                  fused_flops_ratio, inkernel_flops_ratio)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCH_VERSION = 1
 
@@ -96,6 +97,7 @@ def emit_json(path="BENCH_temporal.json"):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", action="store_true",
                     help="emit the machine-readable BENCH_temporal.json "

@@ -30,6 +30,7 @@ import json
 from repro import api
 from repro.core import matrixization as mx
 from repro.core import temporal
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCH_VERSION = 1
 
@@ -124,6 +125,7 @@ def smoke():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", action="store_true",
                     help="emit the machine-readable BENCH_varying.json")

@@ -32,7 +32,8 @@ def main():
     problem = api.StencilProblem(spec, grid=(256, 256), boundary="periodic",
                                  steps=steps, mesh=mesh,
                                  grid_axes=("gx", "gy"))
-    # jnp backend pin: this container runs Pallas in interpret mode only
+    # jnp backend pin: on the CPU the Pallas kernels run in the (slow)
+    # interpreter
     plan = api.plan(problem, backends=["jnp"], max_depth=5)
     print(plan.explain())
 

@@ -77,7 +77,8 @@ def generate_update(plan: StencilPlan) -> GeneratedUpdate:
             f"    t = mx.toeplitz_band({key}, x.shape[lead + {ax}] - {2*r}, dtype=jnp.float32)")
         lines_src.append(
             f"    term = jnp.moveaxis(jnp.tensordot(t, slab.astype(jnp.float32), "
-            f"axes=((1,), (lead + {ax},))), 0, lead + {ax})")
+            f"axes=((1,), (lead + {ax},)), "
+            f"precision=mx.contract_precision(x.dtype)), 0, lead + {ax})")
         lines_src.append("    out = term if out is None else out + term")
     lines_src.append("    return out.astype(x.dtype)")
     source = "\n".join(lines_src)

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core import temporal
 from repro.core.engine import StencilEngine
 from repro.core.stencil_spec import StencilSpec
@@ -53,7 +52,7 @@ def _exchange_axis(block: jnp.ndarray, axis: int, r: int, mesh_axis: str,
     high side.  With non-periodic boundaries the edge devices receive zeros
     (Dirichlet-0), matching the single-device engine's boundary="zero".
     """
-    n_dev = axis_size(mesh_axis)
+    n_dev = lax.axis_size(mesh_axis)
     idx = lax.axis_index(mesh_axis)
 
     lo_strip = lax.slice_in_dim(block, 0, r, axis=axis)            # our low rows
@@ -141,7 +140,7 @@ def _axis_info(block: jnp.ndarray, spec_ndim: int,
     for axis in range(lead, block.ndim):
         n_owned = block.shape[axis]
         if axis in mesh_axes:
-            n_dev = axis_size(mesh_axes[axis])
+            n_dev = lax.axis_size(mesh_axes[axis])
             idx = lax.axis_index(mesh_axes[axis])
         else:
             n_dev, idx = 1, 0
@@ -328,8 +327,7 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
                                    block: tuple[int, ...] | None = None,
                                    fuse_strategy: str = "operator",
                                    batch: int | None = None,
-                                   overlap: bool = True,
-                                   interpret: bool = True) -> DistributedStepper:
+                                   overlap: bool = True) -> DistributedStepper:
     """Build the fused multi-device sweep: one ``t*r`` exchange per chunk.
 
     ``schedule`` is the static list of chunk depths (e.g. ``[4, 4, 2]`` for
@@ -365,7 +363,7 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
     periodic = boundary == "periodic"
 
     base = StencilEngine(spec, option=option, backend=backend, block=block,
-                         boundary="valid", interpret=interpret)
+                         boundary="valid")
     depth_max = max(schedule) if schedule else 1
     cores: dict[int, Callable] = {1: base._core}
     for t in sorted(set(schedule)):
@@ -376,7 +374,7 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
             opt = fused_option if t == depth_max else "auto"
             fused = StencilEngine(temporal.fuse_steps(spec, t), option=opt,
                                   backend=backend, block=base.plan.block,
-                                  boundary="valid", interpret=interpret)
+                                  boundary="valid")
             cores[t] = fused._core
 
     grid_axes = tuple(grid_axes)
@@ -393,8 +391,8 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
                                         periodic=periodic, overlap=overlap)
         return b
 
-    sharded = shard_map(local_fn, mesh=mesh, in_specs=pspec, out_specs=pspec,
-                        check=False)
+    sharded = jax.shard_map(local_fn, mesh=mesh, in_specs=pspec,
+                            out_specs=pspec, check_vma=False)
     fn = jax.jit(sharded,
                  in_shardings=NamedSharding(mesh, pspec),
                  out_shardings=NamedSharding(mesh, pspec))
@@ -426,8 +424,8 @@ def make_distributed_stepper(spec: StencilSpec, mesh: Mesh,
     def global_step(x):
         return lax.fori_loop(0, steps, lambda _, a: sharded(a), x) if steps > 1 else sharded(x)
 
-    sharded = shard_map(local_step, mesh=mesh, in_specs=pspec, out_specs=pspec,
-                        check=False)
+    sharded = jax.shard_map(local_step, mesh=mesh, in_specs=pspec,
+                            out_specs=pspec, check_vma=False)
     return jax.jit(global_step,
                    in_shardings=NamedSharding(mesh, pspec),
                    out_shardings=NamedSharding(mesh, pspec))

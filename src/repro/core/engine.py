@@ -156,9 +156,8 @@ def register_backend(name: str, builder: Callable, *,
                      overwrite: bool = False) -> Backend:
     """Register a stencil execution backend.
 
-    ``builder(plan, **options) -> core`` must return a valid-mode update
-    (shrinks each spatial axis by ``2 * plan.spec.order``); ``options``
-    currently carries ``interpret`` for kernel backends.  Registration is
+    ``builder(plan) -> core`` must return a valid-mode update (shrinks
+    each spatial axis by ``2 * plan.spec.order``).  Registration is
     the extension point third-party kernels use — the engine and the
     planner both dispatch through this table, so a registered backend is
     automatically enumerated, priced (``mxu_efficiency`` modelled fraction
@@ -172,7 +171,7 @@ def register_backend(name: str, builder: Callable, *,
     temporal-blocking core (T base steps per call, shrinking each spatial
     axis by ``2*steps*order``); registering one makes the backend eligible
     for the planner's ``fuse_strategy="inkernel"`` candidates.  ``opts``
-    carries ``interpret`` and the VMEM ``scratch`` policy
+    carries the VMEM ``scratch`` policy
     (``temporal.SCRATCH_MODES``) — accept ``**opts`` so new options stay
     backward-compatible.
 
@@ -215,18 +214,15 @@ def _codegen_builder(plan: StencilPlan, **_opts) -> Callable:
     return generate_update(plan).fn
 
 
-def _pallas_builder(plan: StencilPlan, *, interpret: bool = True,
-                    **_opts) -> Callable:
+def _pallas_builder(plan: StencilPlan, **_opts) -> Callable:
     from repro.kernels import ops as kops
-    return kops.pallas_backend_core(plan, interpret=interpret)
+    return kops.pallas_backend_core(plan)
 
 
 def _pallas_sweep_builder(plan: StencilPlan, steps: int, *,
-                          interpret: bool = True,
                           scratch: str = "pingpong", **_opts) -> Callable:
     from repro.kernels import ops as kops
-    return kops.pallas_sweep_core(plan, steps, interpret=interpret,
-                                  scratch=scratch)
+    return kops.pallas_sweep_core(plan, steps, scratch=scratch)
 
 
 # separable factors the CONSTANT Toeplitz operator through its SVD and
@@ -262,8 +258,7 @@ class StencilEngine:
     def __init__(self, spec: StencilSpec, option: str = "auto",
                  backend: str = "jnp", block: tuple[int, ...] | None = None,
                  unroll: tuple[int, ...] | None = None,
-                 boundary: str = "valid", interpret: bool = True,
-                 scratch: str = "pingpong"):
+                 boundary: str = "valid", scratch: str = "pingpong"):
         if block is None:
             block = default_block(spec)
         if option == "auto":
@@ -276,7 +271,6 @@ class StencilEngine:
                                 backend=backend, block=tuple(block),
                                 unroll=tuple(unroll),
                                 boundary=halo.check_boundary(boundary))
-        self.interpret = interpret
         self.scratch = temporal.check_scratch(scratch)
         self._core = self._build_core()
         self._fn = halo.wrap_boundary(self._core, spec.order, spec.ndim,
@@ -290,11 +284,11 @@ class StencilEngine:
         self._inkernel_cores: dict[tuple[int, str], Callable] = {}
 
     @classmethod
-    def from_execution_plan(cls, eplan, interpret: bool = True) -> "StencilEngine":
+    def from_execution_plan(cls, eplan) -> "StencilEngine":
         """Compatibility constructor from a planner ``ExecutionPlan``."""
         return cls(eplan.spec, option=eplan.base_option, backend=eplan.backend,
                    block=eplan.block, unroll=eplan.unroll,
-                   boundary=eplan.problem["boundary"], interpret=interpret)
+                   boundary=eplan.problem["boundary"])
 
     # -- construction -------------------------------------------------------
     def _build_core(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
@@ -304,7 +298,7 @@ class StencilEngine:
         if not backend.supports(self.plan.spec):
             raise ValueError(f"backend {backend.name!r} does not support "
                              f"{self.plan.spec.describe()}")
-        return backend.builder(self.plan, interpret=self.interpret)
+        return backend.builder(self.plan)
 
     # -- execution -----------------------------------------------------------
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -507,7 +501,6 @@ class StencilEngine:
                                 option=option, backend=self.plan.backend,
                                 block=self.plan.block,
                                 boundary=self.plan.boundary,
-                                interpret=self.interpret,
                                 scratch=self.scratch)
             self._fused_engines[t] = eng
         return eng
@@ -538,8 +531,7 @@ class StencilEngine:
                 raise ValueError(
                     f"backend {self.plan.backend!r} registers no "
                     f"sweep_builder; fuse_strategy='inkernel' needs one")
-            core = be.sweep_builder(self.plan, t, interpret=self.interpret,
-                                    scratch=scratch)
+            core = be.sweep_builder(self.plan, t, scratch=scratch)
             self._inkernel_cores[key] = core
         return core
 

@@ -25,12 +25,14 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.coefficient_lines import CoefficientLine, LineCover
 from repro.core.stencil_spec import StencilSpec
 
 __all__ = [
+    "contract_precision",
     "toeplitz_band",
     "toeplitz_band_np",
     "banded_operator",
@@ -172,6 +174,19 @@ def scenario_scale(acc: jnp.ndarray, spec: StencilSpec,
     return acc
 
 
+def contract_precision(state_dtype):
+    """Matmul precision of the banded contractions for a ``state_dtype`` state.
+
+    f32 states contract at HIGHEST: a TPU runs an f32 ``dot_general`` at
+    DEFAULT precision as a single bf16 pass (TPU v5e, star2d_r2 on 4096²
+    over 16 steps: max error 8.4e-3 against the f32 gather oracle, 1.5e-7
+    at HIGHEST).  Other state dtypes keep the default.
+    """
+    if jnp.dtype(state_dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 def line_to_gather_band(line: CoefficientLine, spec: StencilSpec):
     """(gather band, gather fixed offsets) for an axis-parallel scatter line."""
     if line.is_diagonal:
@@ -209,7 +224,8 @@ def _line_contribution(x: jnp.ndarray, spec: StencilSpec, line: CoefficientLine,
     n_out = x.shape[axis] - 2 * r
     t = toeplitz_band(band, n_out, dtype=dtype)
     # Contract T's halo axis against the slab's line axis.
-    out = jnp.tensordot(t, slab, axes=((1,), (axis,)))
+    out = jnp.tensordot(t, slab, axes=((1,), (axis,)),
+                        precision=contract_precision(x.dtype))
     # tensordot puts the contracted result axis first; restore position.
     return jnp.moveaxis(out, 0, axis)
 
@@ -283,13 +299,16 @@ def separable_apply(x: jnp.ndarray, spec: StencilSpec,
     n_i = x.shape[lead_n] - 2 * r
     n_j = x.shape[lead_n + 1] - 2 * r
     out = None
+    prec = contract_precision(x.dtype)
     for ub, vb in factors:
         ti = toeplitz_band(ub, n_i, dtype=accum_dtype)
         tj = toeplitz_band(vb, n_j, dtype=accum_dtype)
         # (..., i+2r, j+2r) -> contract i then j
-        tmp = jnp.tensordot(ti, x.astype(accum_dtype), axes=((1,), (lead_n,)))
+        tmp = jnp.tensordot(ti, x.astype(accum_dtype), axes=((1,), (lead_n,)),
+                            precision=prec)
         tmp = jnp.moveaxis(tmp, 0, lead_n)
-        tmp = jnp.tensordot(tmp, tj, axes=((lead_n + 1,), (1,)))
+        tmp = jnp.tensordot(tmp, tj, axes=((lead_n + 1,), (1,)),
+                            precision=prec)
         out = tmp if out is None else out + tmp
     return out.astype(x.dtype)
 

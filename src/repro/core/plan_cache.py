@@ -222,12 +222,11 @@ class PlanCache:
     executables without bound).
     """
 
-    def __init__(self, maxsize: int = 32, hw=None, interpret: bool = True):
+    def __init__(self, maxsize: int = 32, hw=None):
         if maxsize < 1:
             raise ValueError("maxsize >= 1")
         self.maxsize = int(maxsize)
         self._hw = hw
-        self._interpret = interpret
         self._entries: OrderedDict[tuple, CachedExecutable] = OrderedDict()
         # plan-without-compile memo (admission-control queries): bounded
         # separately — plans are small frozen records, executables are not
@@ -246,11 +245,6 @@ class PlanCache:
     def hw(self):
         """The hardware model every lookup plans against (None = default)."""
         return self._hw
-
-    @property
-    def interpret(self) -> bool:
-        """Whether compiled executables run Pallas in interpret mode."""
-        return self._interpret
 
     def plan_only(self, problem: StencilProblem, *, calibration=None,
                   **plan_kwargs) -> ExecutionPlan:
@@ -318,7 +312,7 @@ class PlanCache:
         # behind (the miss was already counted — honest accounting)
         chaos.fire("cache.compile", backend=p.backend,
                    batch=int(problem.batch))
-        compiled = compile_plan(p, mesh=mesh, interpret=self._interpret)
+        compiled = compile_plan(p, mesh=mesh)
         # distributed steppers are already jitted; jit single-device fns
         # here so a repeated request cannot re-trace either
         fn = compiled.fn if p.sharding is not None else jax.jit(compiled.fn)
@@ -368,8 +362,7 @@ class PlanCache:
         chaos.fire("cache.compile",
                    backend=rplan.segment_plans[0].backend,
                    batch=int(program.problem.batch))
-        compiled = compile_program(rplan, interpret=self._interpret,
-                                   mesh=mesh)
+        compiled = compile_program(rplan, mesh=mesh)
 
         def fn(x):
             # per-segment sweeps/updates are already jitted inside

@@ -66,6 +66,7 @@ from typing import Any, Callable, Mapping, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import coefficient_lines as cl
@@ -723,7 +724,12 @@ def _hw_dict(hw) -> dict:
 
 
 def _default_hw():
-    from repro.launch.mesh import TPU_V5E
+    """The attached chip's roofline constants on a TPU (an unknown
+    ``device_kind`` raises); the TPU v5e everywhere else, so CPU
+    rehearsals and the golden report plan for the chip this repo targets."""
+    from repro.launch.mesh import TPU_V5E, hardware_for_device
+    if jax.default_backend() == "tpu":
+        return hardware_for_device()
     return TPU_V5E
 
 
@@ -1122,7 +1128,7 @@ def _check_plan_input(x, grid: tuple[int, ...], nd: int, batch: int,
                          f"{lead} (plan with batch={lead[0]} to batch)")
 
 
-def compile_plan(eplan: ExecutionPlan, mesh=None, *, interpret: bool = True,
+def compile_plan(eplan: ExecutionPlan, mesh=None, *,
                  overlap: bool = True) -> CompiledStencil:
     """Materialize an ExecutionPlan into an executable.
 
@@ -1155,7 +1161,7 @@ def compile_plan(eplan: ExecutionPlan, mesh=None, *, interpret: bool = True,
             backend=eplan.backend, boundary=boundary, block=eplan.block,
             fuse_strategy=eplan.fuse_strategy,
             batch=batch if batch > 1 else None,
-            overlap=overlap, interpret=interpret)
+            overlap=overlap)
 
         def _checked(inner):
             # same clear shape errors the single-device fn raises, instead
@@ -1175,8 +1181,7 @@ def compile_plan(eplan: ExecutionPlan, mesh=None, *, interpret: bool = True,
                                stepper=stepper)
 
     eng = StencilEngine(spec, option=eplan.base_option, backend=eplan.backend,
-                        block=eplan.block, boundary=boundary,
-                        interpret=interpret)
+                        block=eplan.block, boundary=boundary)
     strategy = eplan.fuse_strategy
     for t in set(eplan.fuse_schedule):
         if t > 1:

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
 
+from repro.kernels.stencil_mxu import resolve_interpret
+
 __all__ = ["flash_attention_pallas", "flash_attention"]
 
 NEG = -1e30
@@ -55,7 +57,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, seq_len, scale,
 
 
 def flash_attention_pallas(q, k, v, *, block_q: int = 128, block_k: int = 128,
-                           causal: bool = True, interpret: bool = True):
+                           causal: bool = True, interpret: bool | None = None):
     """q/k/v: (B, H, S, Dh) with S % block == 0. Returns (B, H, S, Dh)."""
     b, h, s, dh = q.shape
     block_q = min(block_q, s)
@@ -80,13 +82,13 @@ def flash_attention_pallas(q, k, v, *, block_q: int = 128, block_k: int = 128,
         ],
         out_specs=pl.BlockSpec((None, block_q, dh), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, s, dh)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = True):
+def flash_attention(q, k, v, causal: bool = True, interpret: bool | None = None):
     """Differentiable wrapper: Pallas forward, dense-oracle backward.
 
     The backward pass recomputes probabilities densely (one S x S tile per

@@ -1,17 +1,18 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles padding to block multiples, batch axes, differentiation (custom
-VJPs built from the adjoint stencil), and the interpret/compiled switch.
-On this CPU container kernels always run with ``interpret=True``; on TPU
-the same call sites compile to Mosaic.
+Handles padding to block multiples, batch axes and differentiation
+(custom VJPs built from the adjoint stencil).  ``interpret`` defaults to
+:func:`resolve_interpret`'s platform decision: the Pallas interpreter on
+the CPU backend, Mosaic on a TPU.
 
 Batch axes (leading axes beyond ``spec.ndim``) are FOLDED into the kernel
 as a first-class batch dimension, not vmapped: the whole batch rides one
 ``pallas_call`` whose per-axis Toeplitz contraction stays a single
 ``dot_general`` (band operands built once, shared across the batch — the
 paper's §4.3 input-vector sharing applied across independent states).
-The output is bit-exact against ``jax.vmap`` of the single-state call,
-but amortizes one launch and one operand set over the batch.
+The output matches ``jax.vmap`` of the single-state call to f32 rounding
+(the folded contraction may accumulate in another order), but amortizes
+one launch and one operand set over the batch.
 """
 from __future__ import annotations
 
@@ -28,12 +29,13 @@ from repro.core.stencil_spec import StencilSpec, from_gather_coeffs
 from repro.kernels import ref as kref
 from repro.kernels import stencil_mxu
 from repro.kernels import banded_mixer as bm
+from repro.kernels.stencil_mxu import resolve_interpret
 
 __all__ = ["stencil_matrixized", "stencil_sweep_matrixized", "banded_mix",
-           "pallas_backend_core", "pallas_sweep_core"]
+           "pallas_backend_core", "pallas_sweep_core", "resolve_interpret"]
 
 
-def pallas_backend_core(plan, *, interpret: bool = True):
+def pallas_backend_core(plan):
     """Valid-mode core for the engine/planner backend registry.
 
     ``plan`` is a :class:`repro.core.engine.StencilPlan`; the returned
@@ -41,12 +43,10 @@ def pallas_backend_core(plan, *, interpret: bool = True):
     ``2 * spec.order``) backed by the Pallas MXU kernel.
     """
     return functools.partial(stencil_matrixized, spec=plan.spec,
-                             cover=plan.cover, block=plan.block,
-                             interpret=interpret)
+                             cover=plan.cover, block=plan.block)
 
 
-def pallas_sweep_core(plan, steps: int, *, interpret: bool = True,
-                      scratch: str = "pingpong"):
+def pallas_sweep_core(plan, steps: int, *, scratch: str = "pingpong"):
     """Valid-mode T-step core (the registry's ``sweep_builder`` contract).
 
     Advances ``steps`` applications of ``plan.spec`` per call via the
@@ -63,7 +63,7 @@ def pallas_sweep_core(plan, steps: int, *, interpret: bool = True,
     """
     return functools.partial(stencil_sweep_matrixized, spec=plan.spec,
                              steps=steps, cover=plan.cover, block=plan.block,
-                             interpret=interpret, scratch=scratch,
+                             scratch=scratch,
                              aux_boundary=plan.boundary)
 
 
@@ -203,7 +203,7 @@ def stencil_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
                        block: tuple[int, ...] | None = None,
                        option: str = "parallel",
                        boundary: str = "valid",
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """Stencil via the Pallas MXU kernel. Batch axes lead.
 
     ``boundary`` uses the shared halo layer: 'valid' (default) shrinks the
@@ -257,7 +257,7 @@ def stencil_sweep_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
                              block: tuple[int, ...] | None = None,
                              option: str = "parallel",
                              boundary: str = "valid",
-                             interpret: bool = True,
+                             interpret: bool | None = None,
                              scratch: str = "pingpong",
                              aux_boundary: str | None = None) -> jnp.ndarray:
     """``steps`` stencil applications in ONE in-kernel temporally-blocked
@@ -337,7 +337,8 @@ def stencil_sweep_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def banded_mix(x: jnp.ndarray, band: jnp.ndarray, block_t: int = 128,
-               block_d: int = 128, interpret: bool = True) -> jnp.ndarray:
+               block_d: int = 128,
+               interpret: bool | None = None) -> jnp.ndarray:
     """Differentiable causal banded mix: y[t] = sum_s band[s] x[t-s].
 
     x: (..., T, D).  band: (W,) shared or (W, D) depthwise.
@@ -399,7 +400,7 @@ banded_mix.defvjp(_banded_fwd, _banded_bwd)
 # ---------------------------------------------------------------------------
 
 def stencil_apply_vjp(x: jnp.ndarray, gather_coeffs: jnp.ndarray,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Valid stencil with gradients w.r.t. both input and coefficients.
 
     Forward runs the Pallas kernel; the backward pass IS another stencil —

@@ -14,7 +14,16 @@ from repro.core import halo
 from repro.core.stencil_spec import StencilSpec
 
 __all__ = ["stencil_ref", "stencil_ref_conv", "banded_mixer_ref",
-           "scenario_scale"]
+           "scenario_scale", "REORDER_TOL"]
+
+#: Agreement bar for two executions of the SAME per-state arithmetic whose
+#: contractions may sum in a different order: a batch folded into one
+#: ``dot_general`` vs ``jax.vmap`` of the single-state call, an in-kernel
+#: sweep vs the per-step sequence, a recomputed (retried or resharded)
+#: result vs the first one.  XLA:CPU and the TPU's MXU each choose the
+#: reduction order per operand shape, so such results agree to a few f32
+#: ulps, not to the bit.
+REORDER_TOL = dict(rtol=1e-6, atol=1e-6)
 
 
 def scenario_scale(acc: jnp.ndarray, spec: StencilSpec, ndim: int,

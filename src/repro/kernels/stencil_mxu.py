@@ -2,8 +2,11 @@
 
 One kernel instance owns one output tile (the SME accumulator-register
 analogue, held in VMEM for the whole update — paper observation 1/3).  The
-haloed input slab is an overlapping ``pl.Element`` window of the HBM buffer;
-shifted sub-slabs replace SME's inter-register vector assembling (§4.3).
+haloed input stays in HBM; each instance DMAs its overlapping ``block+2r``
+window into VMEM scratch, rounded up to the (8, 128) tile on the last two
+axes so Mosaic accepts the copy (the input is padded so every rounded
+window is in bounds).  Shifted sub-slabs replace SME's inter-register
+vector assembling (§4.3).
 Every multi-tap coefficient line is executed as a banded-Toeplitz
 contraction on the MXU (the accumulated sum of the line's ``2r+n`` outer
 products, Eq. 12); single-tap lines degrade to VPU scaled-shift adds exactly
@@ -49,7 +52,7 @@ grid leaves idle.
 from __future__ import annotations
 
 import dataclasses
-import functools
+import itertools
 from typing import Sequence
 
 import numpy as np
@@ -62,11 +65,34 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import matrixization as mx
 from repro.core.coefficient_lines import LineCover
 from repro.core.stencil_spec import StencilSpec
-from repro.kernels.pallas_compat import element_block_spec
 
 __all__ = ["KernelPlan", "build_kernel_plan", "stencil_pallas_call",
            "SweepKernelPlan", "build_sweep_kernel_plan", "sweep_pallas_call",
-           "SCRATCH_MODES"]
+           "SCRATCH_MODES", "resolve_interpret"]
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The one interpret-or-compile decision for every Pallas kernel.
+
+    ``None`` follows the default backend: the interpreter on the CPU (tests
+    and tiny rehearsals), Mosaic on a TPU.  Any other platform has no
+    Pallas TPU lowering and is refused.  An explicit ``True`` on a TPU is
+    an error, never a slow path; an explicit ``False`` always compiles
+    (e.g. lowering for a described TPU topology from a CPU process).
+    """
+    platform = jax.default_backend()
+    if interpret is None:
+        if platform == "cpu":
+            return True
+        if platform == "tpu":
+            return False
+        raise RuntimeError(f"no Pallas kernel path for platform "
+                           f"{platform!r}: compiled on 'tpu', interpreted "
+                           f"on 'cpu' only")
+    if interpret and platform == "tpu":
+        raise ValueError("interpret=True on a TPU would run the Pallas "
+                         "interpreter on the host; leave interpret unset")
+    return bool(interpret)
 
 # the canonical scratch-mode registry lives with the other temporal-
 # blocking policy constants (one definition for engine, planner, kernels)
@@ -181,43 +207,122 @@ def build_kernel_plan(spec: StencilSpec, cover: LineCover,
                       n_aux=mx.n_aux_operands(spec))
 
 
+#: Scoped-VMEM cap handed to Mosaic.  The compiler's default (16 MiB on a
+#: v5e) refuses deep 3-D in-kernel sweeps whose aligned slabs and plane
+#: concatenations need more; every chip this repo targets has at least
+#: 64 MiB of VMEM (v5e and v6e: 128 MiB).
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane x 128) VMEM tile: 8 for 32-bit, 16 for bf16."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _window(ext: Sequence[int], dtype) -> tuple[int, ...]:
+    """``ext`` rounded up to the VMEM tile on its last two axes.
+
+    Mosaic refuses a copy or a value whose last two extents are not tile
+    multiples (e.g. a ``(130, 130)`` haloed 128-block), so every VMEM
+    window and scratch slab is allocated at this aligned extent; only its
+    leading ``ext`` part is live.
+    """
+    ext = list(ext)
+    ext[-1] = _round_up(ext[-1], 128)
+    if len(ext) >= 2:
+        ext[-2] = _round_up(ext[-2], _sublanes(dtype))
+    return tuple(ext)
+
+
+def _contract(x, t, pos: int, k: int, precision=None):
+    """``sum_q t[m, q] * x[..., q (at axis pos), ...]`` for ``q < k``.
+
+    The result keeps ``x``'s axes with axis ``pos`` replaced by ``m``.
+    ``x``'s last two extents are tile-aligned (:func:`_window`) and every
+    contraction is issued as ONE 2-D-shaped ``dot_general`` Mosaic lowers:
+
+    * lane axis: the leading axes fold into the rows (aligned second-minor
+      extent) and the band is contracted from the right;
+    * any other axis: every plane over the remaining leading axes is
+      concatenated along the lanes (each plane a 128-multiple wide), so
+      the band multiplies all of them in one pass and the result is split
+      back — batch states and the leading spatial planes fill the MXU's
+      free dimension (§4.3 input-vector sharing).
+    """
+    d = x.ndim
+    m = t.shape[0]
+    f32 = dict(preferred_element_type=jnp.float32, precision=precision)
+    if pos == d - 1:
+        y = x[..., :k]
+        r = jax.lax.dot_general(y.reshape(-1, k), t,
+                                (((1,), (1,)), ((), ())), **f32)
+        return r.reshape(x.shape[:-1] + (m,))
+    if pos < d - 3:
+        raise ValueError(f"contraction axis {pos} of a rank-{d} slab: at "
+                         f"most one leading axis may precede it")
+    major = [a for a in range(d - 2) if a != pos]
+    planes = []
+    for ix in itertools.product(*(range(x.shape[a]) for a in major)):
+        index = [slice(None)] * d
+        for a, i in zip(major, ix):
+            index[a] = i
+        index[pos] = slice(0, k)
+        planes.append(x[tuple(index)])
+    w = x.shape[-1]
+    y = planes[0] if len(planes) == 1 else jnp.concatenate(planes, axis=-1)
+    r = jax.lax.dot_general(t, y, (((1,), (0,)), ((), ())), **f32)
+    if len(planes) == 1:
+        return r.reshape(x.shape[:pos] + (m,) + x.shape[pos + 1:])
+    parts = jnp.stack([r[..., i * w:(i + 1) * w]
+                       for i in range(len(planes))])
+    return parts.reshape(tuple(x.shape[a] for a in major) + r.shape[:-1]
+                         + (w,))
+
+
 def _apply_step(slab, *, spec: StencilSpec, out_ext: tuple[int, ...],
                 axis_ts: Sequence[jnp.ndarray],
                 axis_meta: Sequence[tuple[int, tuple[dict, ...]]],
-                point_taps) -> jnp.ndarray:
+                point_taps, precision=None) -> jnp.ndarray:
     """One matrixized stencil application of a (VMEM-resident) slab value.
 
-    ``slab`` has extent ``out_ext[a] + 2r`` on every spatial axis, with any
-    leading axes treated as batch; the result has extent ``out_ext`` behind
-    the same leading axes.  ``axis_ts[i]`` is the stacked Toeplitz for
-    ``axis_meta[i] = (axis, per-line fixed offsets)`` — ONE ``dot_general``
-    per axis regardless of the batch extent (§4.3 input-vector sharing:
-    the band operand is shared and the batch states' lines stack into the
-    contraction's non-contracted dimension); per-line terms are separated
-    by static row slices and trimmed to the output window on the
-    non-contracted axes.
+    ``slab`` has extent at least ``out_ext[a] + 2r`` on every spatial axis
+    (its last two extents tile-aligned, :func:`_window`; only the leading
+    ``out_ext[a] + 2r`` are read), with any leading axes treated as batch;
+    the result has extent ``out_ext`` behind the same leading axes.
+    ``axis_ts[i]`` is the stacked Toeplitz for ``axis_meta[i] = (axis,
+    per-line fixed offsets)`` — ONE ``dot_general`` per axis regardless of
+    the batch extent (§4.3 input-vector sharing: the band operand is
+    shared and the batch states' lines stack into the contraction's
+    non-contracted dimension); per-line terms are separated by static
+    slices and trimmed to the output window on the non-contracted axes.
+    ``precision`` is the state dtype's :func:`mx.contract_precision`.
     """
     nd, r = spec.ndim, spec.order
     lead = slab.ndim - nd
     out_ext = tuple(out_ext)
     acc = jnp.zeros(slab.shape[:lead] + out_ext, dtype=jnp.float32)
-    slab = slab.astype(jnp.float32)
+    # leading spatial axes (above the two tiled ones) trim for free
+    trim = tuple(slice(0, n + 2 * r) if a < nd - 2 else slice(None)
+                 for a, n in enumerate(out_ext))
+    slab = slab[(slice(None),) * lead + trim].astype(jnp.float32)
     for t, (axis, fixeds) in zip(axis_ts, axis_meta):
         n_a = out_ext[axis]
         # ONE MXU contraction covers every line on this axis (Eq. 12 sums,
-        # batched): (L*n_a, n_a+2r) x slab -> (L*n_a, batch, other extents).
-        term = jax.lax.dot_general(
-            t, slab,
-            dimension_numbers=(((1,), (lead + axis,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        others = [a for a in range(nd) if a != axis]
+        # batched): the axis's extent becomes the stacked L*n_a rows
+        term = _contract(slab, t, lead + axis, n_a + 2 * r, precision)
         for l, fixed_d in enumerate(fixeds):
-            index = [slice(l * n_a, (l + 1) * n_a)]
-            index += [slice(None)] * lead
-            for a in others:
-                off = fixed_d.get(a, 0)
-                index.append(slice(off, off + out_ext[a]))
-            acc = acc + jnp.moveaxis(term[tuple(index)], 0, lead + axis)
+            index = [slice(None)] * lead
+            for a in range(nd):
+                if a == axis:
+                    index.append(slice(l * n_a, (l + 1) * n_a))
+                else:
+                    off = fixed_d.get(a, 0)
+                    index.append(slice(off, off + out_ext[a]))
+            acc = acc + term[tuple(index)]
     for c, gather in point_taps:
         index = (slice(None),) * lead + tuple(
             slice(g, g + n) for g, n in zip(gather, out_ext))
@@ -229,15 +334,17 @@ def _make_kernel(plan: KernelPlan, out_dtype):
     groups = plan.axis_groups()
     axis_meta = [(axis, fixeds) for axis, _, fixeds in groups]
     n_t = len(groups)
+    precision = mx.contract_precision(out_dtype)
 
-    def kernel(x_ref, *refs):
+    def kernel(x_hbm, *refs):
         t_refs = refs[:n_t]
         aux_refs = refs[n_t:n_t + plan.n_aux]
-        o_ref = refs[-1]
-        slab = x_ref[...]
-        acc = _apply_step(slab, spec=plan.spec, out_ext=plan.block,
+        o_ref, win, sem = refs[n_t + plan.n_aux:]
+        _copy_window(x_hbm, win, sem, plan.block)
+        acc = _apply_step(win[...], spec=plan.spec, out_ext=plan.block,
                           axis_ts=[t[...] for t in t_refs],
-                          axis_meta=axis_meta, point_taps=plan.point_taps)
+                          axis_meta=axis_meta, point_taps=plan.point_taps,
+                          precision=precision)
         # scenario operands: output-aligned tiles, f32 elementwise scale
         # (diag(a) @ T factored as contract-then-row-scale); aux carries
         # no batch axis, trailing-dim broadcast covers the batched acc
@@ -274,8 +381,39 @@ def _check_batched_input(x, plan, nd, halo_width):
     return out_shape, tuple(s // b for s, b in zip(out_shape, plan.block))
 
 
+def _windowed(x, block, width: int, grid, dtype=None):
+    """HBM operand + VMEM window extents for overlapping haloed windows.
+
+    Each grid instance copies the ``block + 2*width`` window at its tile
+    origin, rounded up to the VMEM tile (:func:`_window`); the trailing
+    edge is zero-padded so the last rounded window stays in bounds (the
+    extra rows and lanes are never read into an output).  Leading axes of
+    ``x`` beyond ``len(block)`` (the batch) are copied whole.
+    """
+    nd = len(block)
+    lead = x.ndim - nd
+    win = _window([b + 2 * width for b in block], dtype or x.dtype)
+    need = [(g - 1) * b + n for g, b, n in zip(grid, block, win)]
+    pads = [(0, 0)] * lead + [(0, n - s)
+                              for n, s in zip(need, x.shape[lead:])]
+    if any(p[1] for p in pads):
+        x = jnp.pad(x, pads)
+    return x, x.shape[:lead] + win
+
+
+def _copy_window(src_hbm, dst, sem, block) -> None:
+    """DMA this grid instance's window of ``src_hbm`` into ``dst``."""
+    lead = dst.ndim - len(block)
+    index = tuple(pl.ds(0, n) for n in dst.shape[:lead]) + tuple(
+        pl.ds(pl.program_id(a) * b, n)
+        for a, (b, n) in enumerate(zip(block, dst.shape[lead:])))
+    copy = pltpu.make_async_copy(src_hbm.at[index], dst, sem)
+    copy.start()
+    copy.wait()
+
+
 def stencil_pallas_call(x: jnp.ndarray, plan: KernelPlan,
-                        interpret: bool = True,
+                        interpret: bool | None = None,
                         aux: Sequence[jnp.ndarray] = ()) -> jnp.ndarray:
     """Run the matrixized stencil kernel over a haloed spatial array.
 
@@ -299,11 +437,8 @@ def stencil_pallas_call(x: jnp.ndarray, plan: KernelPlan,
         raise ValueError(f"plan expects {plan.n_aux} aux operand(s), "
                          f"got {len(aux)}")
 
-    in_specs = [element_block_spec(
-        lead + tuple(b + 2 * r for b in block),
-        lambda *ids: (0,) * len(lead) + tuple(
-            i * b for i, b in zip(ids, block)),
-    )]
+    x, win = _windowed(x, block, r, grid)
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     t_inputs = []
     for _axis, t, _fixeds in plan.axis_groups():
         t_inputs.append(jnp.asarray(t, jnp.float32))
@@ -325,7 +460,11 @@ def stencil_pallas_call(x: jnp.ndarray, plan: KernelPlan,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(lead + out_shape, x.dtype),
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM(win, x.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=resolve_interpret(interpret),
     )(x, *t_inputs, *aux_inputs)
 
 
@@ -410,26 +549,37 @@ def _make_sweep_kernel(plan: SweepKernelPlan, out_dtype,
     exts = plan.step_exts
     groups_meta = [[(axis, fixeds) for axis, _t, fixeds in groups]
                    for groups in step_groups]
-
+    n_t = sum(len(g) for g in step_groups)
+    n_aux = plan.n_aux
     lead = 0 if plan.batch is None else 1
-
     r = spec.order
+    precision = mx.contract_precision(out_dtype)
 
-    def kernel(x_ref, *refs):
-        n_t = sum(len(g) for g in step_groups)
+    def kernel(x_hbm, *refs):
         t_refs = refs[:n_t]
-        aux_refs = refs[n_t:n_t + plan.n_aux]
-        o_ref = refs[n_t + plan.n_aux]
-        bufs = refs[n_t + plan.n_aux + 1:]  # VMEM scratch (pair, or "single")
-        slab = x_ref[...]              # ([batch,] block + 2*steps*r per axis)
-        aux_slabs = [a[...] for a in aux_refs]  # (block + 2*steps*r per axis)
+        aux_hbm = refs[n_t:n_t + n_aux]
+        o_ref, win = refs[n_t + n_aux:n_t + n_aux + 2]
+        aux_wins = refs[n_t + n_aux + 2:n_t + 2 * n_aux + 2]
+        sem = refs[-1]
+        bufs = refs[n_t + 2 * n_aux + 2:-1]  # VMEM scratch (pair or single)
+        _copy_window(x_hbm, win, sem.at[0], plan.block)
+        for i, (a_hbm, a_win) in enumerate(zip(aux_hbm, aux_wins)):
+            _copy_window(a_hbm, a_win, sem.at[i + 1], plan.block)
+        # the parked slabs are read at their aligned extent: zero them so
+        # the never-written margin holds finite values (it only ever
+        # reaches discarded output rows and lanes)
+        for buf in bufs:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        slab = win[...]                # ([batch,] block + 2*steps*r per axis)
+        aux_slabs = [a[...] for a in aux_wins]
         pos = 0
         for s in range(steps):
             n_groups = len(step_groups[s])
             acc = _apply_step(
                 slab, spec=spec, out_ext=exts[s],
                 axis_ts=[t_refs[pos + g][...] for g in range(n_groups)],
-                axis_meta=groups_meta[s], point_taps=plan.point_taps)
+                axis_meta=groups_meta[s], point_taps=plan.point_taps,
+                precision=precision)
             pos += n_groups
             # scenario scale at EVERY step: step s's live extent sits at
             # offset (s+1)*r per axis inside the aux slab; no leading axis,
@@ -448,13 +598,13 @@ def _make_sweep_kernel(plan: SweepKernelPlan, out_dtype,
                 index = (slice(None),) * lead + tuple(
                     slice(0, n) for n in exts[s])
                 buf[index] = acc
-                slab = buf[index]
+                slab = buf[...]
 
     return kernel
 
 
 def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
-                      interpret: bool = True,
+                      interpret: bool | None = None,
                       aux: Sequence[jnp.ndarray] = ()) -> jnp.ndarray:
     """Advance a haloed spatial array by ``plan.steps`` base steps in-kernel.
 
@@ -469,7 +619,7 @@ def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
     ``aux``: ``plan.n_aux`` SLAB-aligned f32 scenario operands (coefficient
     field, then domain mask), each the same spatial shape as ``x`` (no
     leading axis — shared across the batch) and windowed with the same
-    overlapping element window; the kernel re-reads the right sub-slice at
+    overlapping DMA window; the kernel re-reads the right sub-slice at
     every step, so intermediates are scaled/masked per step (the paper's
     banded-operand traffic tax for varying coefficients).
     """
@@ -483,33 +633,32 @@ def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
                          f"got {len(aux)}")
     slab_shape = tuple(s + 2 * w for s in out_shape)
 
-    in_specs = [element_block_spec(
-        lead + tuple(b + 2 * w for b in block),
-        lambda *ids: (0,) * len(lead) + tuple(
-            i * b for i, b in zip(ids, block)),
-    )]
+    x, win = _windowed(x, block, w, grid)
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     t_inputs = []
     step_groups = [plan.step_groups(s) for s in range(steps)]
     for groups in step_groups:
         for _axis, t, _fixeds in groups:
             t_inputs.append(jnp.asarray(t, jnp.float32))
             in_specs.append(_broadcast_spec(t))
-    aux_inputs = []
+    aux_inputs, aux_wins = [], []
     for a in aux:
         if tuple(a.shape) != slab_shape:
             raise ValueError(f"aux operand shape {a.shape} != haloed slab "
                              f"shape {slab_shape}")
-        aux_inputs.append(jnp.asarray(a, jnp.float32))
-        in_specs.append(element_block_spec(
-            tuple(b + 2 * w for b in block),
-            lambda *ids: tuple(i * b for i, b in zip(ids, block)),
-        ))
+        a, a_win = _windowed(jnp.asarray(a, jnp.float32), block, w, grid)
+        aux_inputs.append(a)
+        aux_wins.append(pltpu.VMEM(a_win, jnp.float32))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     # slab scratch at the deepest intermediate extent: a ping-pong pair by
     # default, one buffer under scratch="single" (half the residency)
-    buf_ext = lead + tuple(b + 2 * (steps - 1) * r for b in block)
+    buf_ext = lead + _window([b + 2 * (steps - 1) * r for b in block],
+                             jnp.float32)
     n_bufs = 1 if plan.scratch == "single" else 2
-    scratch = [pltpu.VMEM(buf_ext, jnp.float32) for _ in range(n_bufs)]
+    scratch = ([pltpu.VMEM(win, x.dtype)] + aux_wins
+               + [pltpu.VMEM(buf_ext, jnp.float32) for _ in range(n_bufs)]
+               + [pltpu.SemaphoreType.DMA((1 + len(aux),))])
 
     out_spec = pl.BlockSpec(lead + block,
                             lambda *ids: (0,) * len(lead) + tuple(ids))
@@ -521,5 +670,7 @@ def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(lead + out_shape, x.dtype),
         scratch_shapes=scratch,
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=resolve_interpret(interpret),
     )(x, *t_inputs, *aux_inputs)

@@ -154,7 +154,7 @@ class CalibrationRecord:
 
 def measure_candidate(problem: StencilProblem, depth: int, option: str,
                       backend: str, block: tuple[int, ...], *,
-                      interpret: bool = True, wall: bool = False,
+                      wall: bool = False,
                       repeats: int = 3,
                       base_option: str | None = None,
                       strategy: str = "operator") -> CandidateMeasurement:
@@ -181,7 +181,7 @@ def measure_candidate(problem: StencilProblem, depth: int, option: str,
         base_opt = base_option or "auto"
     eng = StencilEngine(spec, option=base_opt,
                         backend=backend, block=tuple(block),
-                        boundary=problem.boundary, interpret=interpret)
+                        boundary=problem.boundary)
     if depth > 1:
         if strategy == "inkernel":
             eng.inkernel_core(depth)
@@ -215,7 +215,7 @@ def measure_candidate(problem: StencilProblem, depth: int, option: str,
 
 
 def calibrate(problem: StencilProblem, hw=None, *, top_k: int = 3,
-              wall: bool = False, interpret: bool = True,
+              wall: bool = False,
               **plan_kwargs) -> CalibrationRecord:
     """Measure a problem's top-K planned candidates into a record.
 
@@ -229,7 +229,7 @@ def calibrate(problem: StencilProblem, hw=None, *, top_k: int = 3,
     ranked = p.ranked()[:max(1, top_k)]
     measurements = [
         measure_candidate(problem, c.depth, c.option, c.backend, c.block,
-                          interpret=interpret, wall=wall,
+                          wall=wall,
                           base_option=plan_kwargs.get("option"),
                           strategy=c.strategy)
         for c in ranked]

@@ -22,7 +22,6 @@ import traceback
 
 import jax
 
-from repro.compat import spmd_donate_argnums
 from repro.configs.base import ARCH_IDS, SHAPE_CELLS, cells_for, get_config
 from repro.launch.cells import MODEL_FLOPS, build_cell
 from repro.launch.hlo_analysis import analyze_hlo
@@ -44,7 +43,7 @@ def run_cell(arch: str, cell_name: str, multi_pod: bool, ce_chunk: int = 512,
     with rules.activate(mesh):
         jitted = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                          out_shardings=spec.out_shardings,
-                         donate_argnums=spmd_donate_argnums(spec.donate))
+                         donate_argnums=spec.donate)
         lowered = jitted.lower(*spec.args)
         t_lower = time.time()
         compiled = lowered.compile()

@@ -110,6 +110,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.core.plan_cache import PlanCache
 from repro.core.planner import StencilProblem
 from repro.core.stencil_spec import PAPER_SUITE, StencilSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rollout.program import RolloutProgram, Segment, as_segments
 from repro.runtime import chaos
 from repro.runtime.fault_tolerance import RestartPolicy
@@ -333,7 +334,7 @@ class StencilServer:
                  boundary: str = "periodic", dtype: str = "float32",
                  max_batch: int = 8, cache: PlanCache | None = None,
                  backends: Sequence[str] | None = None,
-                 interpret: bool = True, hw=None,
+                 hw=None,
                  async_dispatch: bool = True,
                  admission: bool = True, admission_rtol: float = 0.0,
                  devices: Sequence | None = None,
@@ -402,14 +403,12 @@ class StencilServer:
                 raise ValueError("mesh_axes/grid_axes need mesh_shape")
             self.mesh_shape = None
             self.mesh_axes = self.grid_axes = ()
-        base = cache if cache is not None else PlanCache(
-            hw=hw, interpret=interpret)
+        base = cache if cache is not None else PlanCache(hw=hw)
         #: one PlanCache per device — jit executables are per-device, so
         #: sharing one entry across devices would mix their warm/compile
         #: accounting and recompile under a single ``calls`` counter
         self.caches: list[PlanCache] = [base] + [
-            PlanCache(maxsize=base.maxsize, hw=base.hw,
-                      interpret=base.interpret)
+            PlanCache(maxsize=base.maxsize, hw=base.hw)
             for _ in self._devices[1:]]
         self.cache = self.caches[0]
         self._pending: list[_Request] = []
@@ -1333,8 +1332,9 @@ def main() -> None:
                          "is mixed in to exercise shape grouping)")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--boundary", default="periodic")
-    ap.add_argument("--backends", default="jnp",
-                    help="comma-separated backend pin ('' = full search)")
+    ap.add_argument("--backends", default="",
+                    help="comma-separated backend pin (default: the "
+                         "planner's full search)")
     ap.add_argument("--sync", action="store_true",
                     help="synchronous dispatch (settle each bucket "
                          "immediately) instead of overlapped")
@@ -1351,6 +1351,7 @@ def main() -> None:
                          "retry ladder must recover; see "
                          "repro.runtime.chaos)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = PAPER_SUITE()[args.cell]
     backends = [b for b in args.backends.split(",") if b] or None

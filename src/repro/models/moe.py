@@ -18,8 +18,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import (axis_size as lax_axis_size,
-                          partial_auto_shard_map_ok, shard_map)
 from repro.models.layers import dense_init
 from repro.sharding.rules import axis_size, current_mesh, shard
 
@@ -69,12 +67,12 @@ def moe_ffn(p, x, mcfg, act: str = "silu", dropless: bool = False) -> MoEOut:
     tp = axis_size("tp")
     mesh = current_mesh()
     if mesh is not None and tp > 1 and mcfg.num_experts % tp == 0 \
-            and "model" in mesh.axis_names and partial_auto_shard_map_ok():
+            and "model" in mesh.axis_names:
         from jax.sharding import PartitionSpec as P
 
         def local_fn(xg_l, router, wig, wiu, wo):
             xg_l = xg_l.astype(x.dtype)
-            nsh = lax_axis_size("model")
+            nsh = jax.lax.axis_size("model")
             midx = jax.lax.axis_index("model")
             e_loc = mcfg.num_experts // nsh
             p_l = {"router": router, "wi_gate": wig, "wi_up": wiu, "wo": wo}
@@ -91,13 +89,13 @@ def moe_ffn(p, x, mcfg, act: str = "silu", dropless: bool = False) -> MoEOut:
             aux = jax.lax.pmean(aux, "model")
             return y_sum.astype(y_part.dtype), aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(P(), P(), P("model"), P("model"), P("model")),
             out_specs=(P(), P()),
             axis_names={"model"},
-            check=False,
+            check_vma=False,
         )(xg.astype(jnp.float32),  # f32 boundary: the implicit input-
           # cotangent psum must not be bf16 (XLA-CPU AllReducePromotion bug)
           p["router"], p["wi_gate"], p["wi_up"], p["wo"])

@@ -89,7 +89,6 @@ class CompiledRollout:
     sweeps: tuple[Callable, ...]          # one jitted fused sweep per segment
     updates: tuple[Callable | None, ...]  # jitted pointwise update or None
     mesh: Any = None                      # live Mesh of distributed sweeps
-    interpret: bool = True                # recorded for reshard recompiles
 
     def run_segment(self, i: int, x):
         """Advance one segment: fused sweep, then the update op."""
@@ -123,7 +122,7 @@ class CompiledRollout:
 
 
 def compile_program(rplan: RolloutPlan | RolloutProgram, *,
-                    interpret: bool = True, hw=None, mesh=None,
+                    hw=None, mesh=None,
                     **plan_kwargs) -> CompiledRollout:
     """Materialize a rollout plan (planning first if given a program).
 
@@ -151,7 +150,7 @@ def compile_program(rplan: RolloutPlan | RolloutProgram, *,
         pj = p.to_json()
         fn = sweep_by_plan.get(pj)
         if fn is None:
-            cp = compile_plan(p, mesh=mesh, interpret=interpret)
+            cp = compile_plan(p, mesh=mesh)
             # distributed sweeps are already jitted inside the stepper
             # (and their host-side chaos wrapper must NOT be traced);
             # single-device fns pick up their jit here as before
@@ -173,7 +172,7 @@ def compile_program(rplan: RolloutPlan | RolloutProgram, *,
         updates.append(ufn)
     return CompiledRollout(plan=rplan, program=program,
                            sweeps=tuple(sweeps), updates=tuple(updates),
-                           mesh=mesh, interpret=interpret)
+                           mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -222,8 +221,7 @@ def _reshard_compiled(compiled: CompiledRollout,
         if p.sharding is not None else p
         for p in compiled.plan.segment_plans)
     rplan = dataclasses.replace(compiled.plan, segment_plans=plans)
-    return compile_program(rplan, interpret=compiled.interpret,
-                           mesh=new_mesh)
+    return compile_program(rplan, mesh=new_mesh)
 
 
 def _state_sharding(compiled: CompiledRollout) -> NamedSharding | None:

@@ -9,8 +9,9 @@ axis.  The bars are the repo-wide ones:
 * ``atol=1e-4`` against the iterated gather oracle (XLA:CPU contracts the
   banded dots with FMA, so exact equality across ``steps`` applications is
   not the right bar — see DESIGN.md §Numerics);
-* BIT-exactness of a batched sweep against ``jax.vmap`` of the same
-  closure (folding states must not change the per-state arithmetic);
+* agreement to f32 rounding (:data:`REORDER_TOL`) of a batched sweep
+  against ``jax.vmap`` of the same closure (folding states must not change
+  the per-state arithmetic, but may change its accumulation order);
 * an ILLEGAL explicit (strategy, depth) pin — e.g. operator fusion at
   depth > 1 over a varying-coefficient spec — must raise ``ValueError``
   from the engine, never silently apply the constant-coefficient fused
@@ -34,9 +35,10 @@ from repro.core import stencil_spec as ss
 from repro.core import temporal
 from repro.core.engine import StencilEngine
 from repro.core.time_stepper import reference_evolve
+from repro.kernels.ref import REORDER_TOL
 
-__all__ = ["SCENARIOS", "parity_grid", "draw_base_spec", "with_scenario",
-           "draw_scenario_spec", "assert_sweep_parity"]
+__all__ = ["SCENARIOS", "REORDER_TOL", "parity_grid", "draw_base_spec",
+           "with_scenario", "draw_scenario_spec", "assert_sweep_parity"]
 
 #: Coefficient/domain scenarios a spec can carry (plan dimensions, ISSUE 8).
 SCENARIOS = ("constant", "varying", "masked", "varying+masked")
@@ -90,8 +92,8 @@ def assert_sweep_parity(spec, boundary: str, strategy: str = "auto",
     """Fused-sweep parity for one (spec, boundary, strategy, depth, batch).
 
     ``batch=0`` runs a single un-batched state; ``batch>=1`` folds that
-    many states and additionally requires bit-exactness against
-    ``jax.vmap`` of the same sweep closure.  ``depth`` is the fuse pin
+    many states and additionally requires agreement within
+    :data:`REORDER_TOL` with ``jax.vmap`` of the same sweep closure.  ``depth`` is the fuse pin
     (int) or ``"auto"``.  If the explicit (strategy, depth) pin is illegal
     for the spec/boundary (``temporal.fusion_legal``), the engine MUST
     refuse with ``ValueError`` — the harness asserts that and returns
@@ -129,7 +131,7 @@ def assert_sweep_parity(spec, boundary: str, strategy: str = "auto",
         np.asarray(out), np.asarray(ref), atol=atol,
         err_msg=f"sweep diverged from gather oracle: {label}")
     if batch:
-        np.testing.assert_array_equal(
-            np.asarray(out), np.asarray(jax.vmap(fn)(x)),
-            err_msg=f"batched sweep not bit-exact vs vmap: {label}")
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(jax.vmap(fn)(x)), **REORDER_TOL,
+            err_msg=f"batched sweep diverged from vmap: {label}")
     return out

@@ -1,12 +1,14 @@
 """Batched sweep execution: the batch axis folded into the MXU
 contractions, end to end.
 
-The acceptance bar is BIT-exactness against a ``jax.vmap`` of the
-single-state ``sweep_fn``: folding B states into one kernel instance
-issues the SAME per-state banded-Toeplitz contractions (the batch rides
-the slab operand of each ``dot_general``; the band operand is shared), so
-the batched output must equal the vmapped per-state reference to the last
-bit.  The structural claim is checked on the jaxpr: the per-axis
+The acceptance bar is agreement to f32 rounding (``parity.REORDER_TOL``)
+with a ``jax.vmap`` of the single-state ``sweep_fn``: folding B states
+into one kernel instance issues the SAME per-state banded-Toeplitz
+contractions (the batch rides the slab operand of each ``dot_general``;
+the band operand is shared), but the folded contraction has other operand
+shapes, and XLA:CPU and the MXU pick the reduction order per shape — so
+the batched output matches the vmapped per-state reference to a few ulps,
+not to the bit.  The structural claim is checked on the jaxpr: the per-axis
 ``dot_general`` count does NOT grow with B.  The cost-model claim —
 batching fills the MXU rows a small grid leaves idle and amortizes the
 per-chunk dispatch overhead, so modelled per-STATE cost falls with B —
@@ -19,7 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from parity import assert_sweep_parity, parity_grid
+from parity import REORDER_TOL, assert_sweep_parity, parity_grid
 from repro import api
 from repro.core import matrixization as mx
 from repro.core import stencil_spec as ss
@@ -43,8 +45,8 @@ def _grid_for(spec, steps=4):
 
 
 def _check_batched_parity(spec, boundary, batch, strategy, steps=4, fuse=2):
-    # the shared harness does both bars: bit-exact vs jax.vmap of the same
-    # sweep closure, and atol=1e-4 vs the iterated gather oracle
+    # the shared harness does both bars: REORDER_TOL vs jax.vmap of the
+    # same sweep closure, and atol=1e-4 vs the iterated gather oracle
     assert_sweep_parity(spec, boundary, strategy, fuse, batch, steps=steps,
                         seed=batch * 10 + steps)
 
@@ -134,8 +136,8 @@ def test_oversized_batch_folds_in_vmem_feasible_chunks():
                                  boundary="periodic")
     fn = lambda a: ops.stencil_matrixized(a, spec=spec, block=(256, 256),
                                           boundary="periodic")
-    np.testing.assert_array_equal(np.asarray(out[:2]),
-                                  np.asarray(jax.vmap(fn)(x[:2])))
+    np.testing.assert_allclose(np.asarray(out[:2]),
+                               np.asarray(jax.vmap(fn)(x[:2])), **REORDER_TOL)
     # a single over-budget state stays exactly as feasible as pre-batching
     assert _feasible_fold(4, lambda c: float("inf")) == 1
 
@@ -145,8 +147,8 @@ def test_batched_single_step_matches_vmap_bit_exact():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(8, 40, 40)), jnp.float32)
     fn = lambda a: ops.stencil_matrixized(a, spec=spec, boundary="periodic")
-    np.testing.assert_array_equal(np.asarray(fn(x)),
-                                  np.asarray(jax.vmap(fn)(x)))
+    np.testing.assert_allclose(np.asarray(fn(x)),
+                               np.asarray(jax.vmap(fn)(x)), **REORDER_TOL)
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +158,8 @@ def test_batched_single_step_matches_vmap_bit_exact():
 def test_batched_bf16_vs_f32_tolerance():
     """bf16 inputs ride the same batched kernel (f32 accumulation inside),
     so the batched bf16 sweep must track the f32 one to bf16 resolution
-    and stay bit-exact against its own vmapped reference."""
+    and its own vmapped reference to one bf16 rounding (an f32 ulp of
+    reordering can flip the final bf16 rounding)."""
     spec = SUITE["box2d_r1"]
     rng = np.random.default_rng(11)
     xf = jnp.asarray(rng.normal(size=(4, 40, 40)), jnp.float32)
@@ -165,8 +168,9 @@ def test_batched_bf16_vs_f32_tolerance():
     fn = eng.sweep_fn(4, fuse=2, grid=(40, 40))
     out_b, out_f = fn(xb), fn(xf)
     assert out_b.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(np.asarray(out_b, np.float32),
-                                  np.asarray(jax.vmap(fn)(xb), np.float32))
+    np.testing.assert_allclose(np.asarray(out_b, np.float32),
+                               np.asarray(jax.vmap(fn)(xb), np.float32),
+                               rtol=2 ** -7, atol=2 ** -7)
     # bf16 has ~3 decimal digits; the evolution is contractive (weights
     # sum to 1) so absolute tolerance at bf16 epsilon scale is the bar
     np.testing.assert_allclose(np.asarray(out_b, np.float32),
@@ -241,9 +245,9 @@ def test_compile_batched_plan_matches_vmapped_compile():
                                     block=(16, 16)))
         run3 = api.compile(api.plan(prob3, fuse=2, backends=["pallas"],
                                     block=(16, 16)))
-        np.testing.assert_array_equal(np.asarray(run3(x)),
-                                      np.asarray(jax.vmap(run1.fn)(x)),
-                                      err_msg=boundary)
+        np.testing.assert_allclose(np.asarray(run3(x)),
+                                   np.asarray(jax.vmap(run1.fn)(x)),
+                                   **REORDER_TOL, err_msg=boundary)
         # a batched plan rejects un-batched input
         with pytest.raises(ValueError):
             run3(x[0])

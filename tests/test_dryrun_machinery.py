@@ -8,7 +8,6 @@ from test_multidevice import run_with_devices
 def test_cells_lower_on_small_mesh():
     run_with_devices("""
         import jax
-        from repro.compat import spmd_donate_argnums
         from repro.configs.base import get_smoke_config
         from repro.launch.cells import build_cell
         from repro.launch.mesh import make_mesh
@@ -33,7 +32,7 @@ def test_cells_lower_on_small_mesh():
             with rules.activate(mesh):
                 compiled = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                                    out_shardings=spec.out_shardings,
-                                   donate_argnums=spmd_donate_argnums(spec.donate)
+                                   donate_argnums=spec.donate
                                    ).lower(*spec.args).compile()
             cost = analyze_hlo(compiled.as_text())
             assert cost.dot_flops > 0, (arch, cell)

@@ -110,7 +110,6 @@ def test_shard_map_dp_gradient_sync_with_compression():
     run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import shard_map
         from repro.launch.mesh import make_mesh
 
         mesh = make_mesh((8,), ("data",))
@@ -125,8 +124,8 @@ def test_shard_map_dp_gradient_sync_with_compression():
             # stacks an implicit psum on top of pmean (measured exactly 8x)
             return jax.lax.pmean(gw.astype(jnp.float32), axis_name="data")
 
-        f = shard_map(dp, mesh=mesh, in_specs=(P(), P("data"), P("data")),
-                      out_specs=P(), check=False)
+        f = jax.shard_map(dp, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+                          out_specs=P(), check_vma=False)
         g_dp = f(w, x, y)
         g_ref = jax.grad(lambda w: jnp.mean((x @ w - y) ** 2))(w)
         err = float(jnp.abs(g_dp - g_ref).max()) / (float(jnp.abs(g_ref).max()) + 1e-9)
