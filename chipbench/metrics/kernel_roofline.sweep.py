@@ -1,0 +1,2 @@
+"""Mosaic kernels' share of their roofline in the sweep window."""
+from chipbench.reduce import kernel_roofline as read  # noqa: F401
