@@ -81,8 +81,8 @@ from repro.core.stencil_spec import (PAPER_SUITE, StencilSpec, box, diagonal,
                                      random_domain_mask, star)
 from repro.launch.calibrate import (CalibrationRecord, CandidateMeasurement,
                                     calibrate, measure_candidate)
-from repro.launch.serve_stencil import (RequestShed, ServeStats,
-                                        StencilServer)
+from repro.launch.serve_stencil import (SERVE_SPANS, RequestShed,
+                                        ServeStats, StencilServer)
 from repro.rollout import (CompiledRollout, RolloutPlan, RolloutProgram,
                            RolloutResult, Segment, UpdateOp, compile_program,
                            plan_program, register_update_op, run_checkpointed,
@@ -102,7 +102,7 @@ __all__ = [
     "CalibrationRecord", "CandidateMeasurement", "calibrate",
     "measure_candidate",
     "PlanCache", "CachedExecutable", "cache_key",
-    "StencilServer", "ServeStats", "RequestShed",
+    "StencilServer", "ServeStats", "RequestShed", "SERVE_SPANS",
     "FaultPlan", "FaultRule", "FaultError", "FAULT_SITES",
     "RestartPolicy", "HeartbeatMonitor", "StepTimeout", "supervised",
     "RolloutProgram", "Segment", "UpdateOp", "RolloutPlan", "RolloutResult",

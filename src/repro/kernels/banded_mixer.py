@@ -103,4 +103,5 @@ def banded_mixer_pallas_call(x: jnp.ndarray, band: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM(win, x.dtype),
                         pltpu.SemaphoreType.DMA(())],
         interpret=smx.resolve_interpret(interpret),
+        name="banded_mixer",
     )(xp, const)

@@ -83,6 +83,7 @@ def flash_attention_pallas(q, k, v, *, block_q: int = 128, block_k: int = 128,
         out_specs=pl.BlockSpec((None, block_q, dh), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dh), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, s, dh)
 

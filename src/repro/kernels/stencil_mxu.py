@@ -48,6 +48,10 @@ systolic array is symmetric in its two free dimensions and tiles each in
 pass slots).  The per-axis dot count is therefore independent of B,
 which is exactly how batching fills the MXU slots that a single small
 grid leaves idle.
+
+Each ``pallas_call`` carries a stable ``name`` (``stencil_step``,
+``stencil_sweep``) with no shape or depth in it: the compiled custom
+call takes that name, so a profiler trace tells the two kernels apart.
 """
 from __future__ import annotations
 
@@ -465,6 +469,7 @@ def stencil_pallas_call(x: jnp.ndarray, plan: KernelPlan,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
+        name="stencil_step",
     )(x, *t_inputs, *aux_inputs)
 
 
@@ -673,4 +678,5 @@ def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
+        name="stencil_sweep",
     )(x, *t_inputs, *aux_inputs)

@@ -17,7 +17,7 @@ user states (arbitrary arrival order, mixed grid shapes) is advanced
      precise operand geometry behind the "batch-in-M" shorthand).
   3. **launch amortization** — one kernel dispatch per chunk serves the
      whole bucket (the planner's ``LAUNCH_OVERHEAD_S / (depth * batch)``
-     term, measured here as per-state wall clock).
+     term).
   4. **dispatch overlap** — the scheduler is ``step()``-driven
      continuous batching: every turn admits whatever is pending RIGHT
      NOW into freshly dispatched buckets (no waiting for a bucket to
@@ -45,8 +45,12 @@ intermediates stream incrementally via ``rollout_results(ticket)``, and
 the final state settles like any plain result.
 
 Per-request latency (submit -> settled result) is tracked next to the
-throughput counters — p50/p95/mean in ``stats()["latency"]`` — and
-``submit(state, deadline_s=...)`` counts deadline misses.  A
+bucket counters — p50/p95/mean in ``stats()["latency"]`` — and
+``submit(state, deadline_s=...)`` counts deadline misses.  Where the
+host's turn goes is not counted: the turn carries profiler spans
+(``SERVE_SPANS``), written by :class:`jax.profiler.TraceAnnotation` into
+the same trace as the device ops and on the same clock, and free while
+no profiler runs.  A
 **multi-device** server (``devices=jax.devices()``) routes shape groups
 round-robin across devices, each with its own :class:`PlanCache`, and
 reports a per-device column.
@@ -105,6 +109,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.plan_cache import PlanCache
@@ -115,7 +120,20 @@ from repro.rollout.program import RolloutProgram, Segment, as_segments
 from repro.runtime import chaos
 from repro.runtime.fault_tolerance import RestartPolicy
 
-__all__ = ["StencilServer", "ServeStats", "RequestShed"]
+__all__ = ["StencilServer", "ServeStats", "RequestShed", "SERVE_SPANS"]
+
+#: The profiler spans of the scheduler's turn, outermost first.  ``turn``
+#: wraps one ``step()`` (arg ``turn``, its sequence number); inside it
+#: ``stack`` (zero padding, ``jnp.stack``, ``device_put``), ``lookup``
+#: (the plan cache) and ``launch`` (the executable's dispatch) form one
+#: bucket, ``wait`` blocks on a bucket dispatched on an earlier turn and
+#: ``book`` files its results.  Each bucket's spans share the arg
+#: ``bucket_id``; ``launch`` lists its ``tickets``.  ``idle`` is the
+#: background stepper waiting for work, outside any turn.
+SERVE_SPANS = ("stencil.serve.turn", "stencil.serve.stack",
+               "stencil.serve.lookup", "stencil.serve.launch",
+               "stencil.serve.wait", "stencil.serve.book",
+               "stencil.serve.idle")
 
 
 class RequestShed(RuntimeError):
@@ -199,6 +217,7 @@ class _InFlight:
     out: jnp.ndarray         # (final, emits) pytree for rollout buckets
     t0: float                # dispatch time (perf_counter)
     device: int              # index into the server's device list
+    bucket_id: int           # links the bucket's profiler spans
     segment: Segment | None = None   # the rollout hop this bucket ran
 
 
@@ -206,17 +225,12 @@ class _InFlight:
 class ServeStats:
     """Aggregate serving counters (see :meth:`StencilServer.stats`).
 
-    ``wall_s``/``warm_states`` cover only batches whose executable had
-    already completed at least once, so ``per_state_s`` is the
-    steady-state sweep wall clock; each executable's FIRST call (jit
-    trace + compile + sweep) is accounted separately in
-    ``compile_wall_s`` — otherwise the launch-amortization metric would
-    be compile-dominated until enough warm traffic diluted it.  Under
-    overlapped dispatch a bucket's wall clock spans dispatch -> settled,
-    which includes any time it queued behind earlier buckets on the
-    device: the per-bucket numbers are honest completion spans, the
-    end-to-end win of overlap shows up in whole-stream wall clock
-    (``benchmarks/bench_serve.py`` measures both).
+    ``compile_wall_s`` sums each executable's FIRST call (jit trace +
+    compile + sweep), dispatch -> settled.  Warm buckets book no time
+    here: under overlapped dispatch a bucket's dispatch -> settled span
+    includes its queueing behind earlier buckets, so per-bucket wall
+    clock double-counts.  Where a warm turn's time goes is read from the
+    profiler spans ``SERVE_SPANS`` instead.
 
     ``latencies_s`` records every request's submit -> settled latency
     (the queue + batching + device time a caller actually waits);
@@ -240,8 +254,6 @@ class ServeStats:
     requests: int = 0
     batches: int = 0
     padded_states: int = 0
-    wall_s: float = 0.0          # warm-executable sweep seconds
-    warm_states: int = 0         # states served by warm executables
     compile_wall_s: float = 0.0  # first-call (trace+compile+sweep) seconds
     deadline_misses: int = 0
     bucket_failures: int = 0
@@ -253,16 +265,6 @@ class ServeStats:
     rollout_recovered: int = 0
     shed: int = 0
     latencies_s: list = dataclasses.field(default_factory=list, repr=False)
-
-    @property
-    def per_state_s(self) -> float:
-        """Warm sweep seconds per state (0 until any warm batch ran)."""
-        return self.wall_s / self.warm_states if self.warm_states else 0.0
-
-    @property
-    def throughput(self) -> float:
-        """Warm-served states per second of sweep wall-clock."""
-        return self.warm_states / self.wall_s if self.wall_s else 0.0
 
     def latency_percentile(self, q: float) -> float:
         """Latency percentile in seconds (0.0 with no settled requests)."""
@@ -418,6 +420,8 @@ class StencilServer:
         self._failed: dict[int, Exception] = {}
         self._cancelled: set[int] = set()
         self._next_ticket = 0
+        self._next_bucket = 0           # bucket_id of the next dispatch
+        self._turns = 0                 # scheduler turns begun
         self._caps: dict[tuple[int, ...], int] = {}
         self._group_dev: dict[tuple[int, ...], int] = {}
         self._group_mesh: dict[tuple[int, ...], Mesh] = {}
@@ -685,7 +689,8 @@ class StencilServer:
                 has_work = ((self._pending or self._inflight)
                             and self._stepper_error is None)
             if not has_work:
-                self._work.wait(timeout=poll_s)
+                with TraceAnnotation("stencil.serve.idle"):
+                    self._work.wait(timeout=poll_s)
                 self._work.clear()
                 continue
             try:
@@ -885,12 +890,11 @@ class StencilServer:
         whose next hop matches.
         """
         b = _bucket(len(chunk), cap)
-        states = [r.state for r in chunk]
-        states += [jnp.zeros(shape, jnp.dtype(self.dtype))] * (b - len(chunk))
-        batch_arr = jnp.stack(states)
         di = self._device_of(shape)
         dev = self._devices[di]
         with self._lock:
+            bid = self._next_bucket
+            self._next_bucket += 1
             mesh = (self._group_mesh_for(shape)
                     if self.mesh_shape is not None else None)
             seg = chunk[0].rollout.current if chunk[0].rollout else None
@@ -898,33 +902,44 @@ class StencilServer:
                 r.attempts += 1
             if seg is not None:
                 self.stats_.rollout_attempts += len(chunk)
-        arg = batch_arr[0] if b == 1 else batch_arr
-        if mesh is not None:
-            lead = [None] if b > 1 else []
-            axes = [a if a else None for a in self.grid_axes]
-            arg = jax.device_put(arg, NamedSharding(
-                mesh, PartitionSpec(*(lead + axes))))
-        elif dev is not None:
-            arg = jax.device_put(arg, dev)
-        if seg is not None:
-            program = RolloutProgram(
-                self._problem(shape, b, steps=seg.steps, mesh=mesh), (seg,))
-            entry = self.caches[di].get_program(program, mesh=mesh,
-                                               **self._plan_kwargs(shape))
-        else:
-            entry = self.caches[di].get(self._problem(shape, b, mesh=mesh),
-                                        mesh=mesh,
-                                        **self._plan_kwargs(shape))
+        with TraceAnnotation("stencil.serve.stack", bucket_id=bid, size=b,
+                             shape=_shape_str(shape)):
+            states = [r.state for r in chunk]
+            states += [jnp.zeros(shape, jnp.dtype(self.dtype))] * (
+                b - len(chunk))
+            batch_arr = jnp.stack(states)
+            arg = batch_arr[0] if b == 1 else batch_arr
+            if mesh is not None:
+                lead = [None] if b > 1 else []
+                axes = [a if a else None for a in self.grid_axes]
+                arg = jax.device_put(arg, NamedSharding(
+                    mesh, PartitionSpec(*(lead + axes))))
+            elif dev is not None:
+                arg = jax.device_put(arg, dev)
+        with TraceAnnotation("stencil.serve.lookup", bucket_id=bid):
+            if seg is not None:
+                program = RolloutProgram(
+                    self._problem(shape, b, steps=seg.steps, mesh=mesh),
+                    (seg,))
+                entry = self.caches[di].get_program(
+                    program, mesh=mesh, **self._plan_kwargs(shape))
+            else:
+                entry = self.caches[di].get(
+                    self._problem(shape, b, mesh=mesh), mesh=mesh,
+                    **self._plan_kwargs(shape))
         chaos.fire("serve.dispatch", shape=_shape_str(shape), device=di,
                    bucket=b)
         t0 = time.perf_counter()
         # dispatch only — readiness (and the entry's success accounting)
         # is deferred to _settle, so a failed first call stays cold and
         # host-side prep of the next bucket overlaps this device work
-        out = entry.dispatch(arg)
+        with TraceAnnotation("stencil.serve.launch", bucket_id=bid,
+                             requests=len(chunk),
+                             tickets=" ".join(str(r.ticket) for r in chunk)):
+            out = entry.dispatch(arg)
         return _InFlight(shape=shape, requests=list(chunk), bucket=b,
                          entry=entry, out=out, t0=t0, device=di,
-                         segment=seg)
+                         bucket_id=bid, segment=seg)
 
     def _salvage(self) -> None:
         """Settle whatever is in flight before propagating a primary
@@ -1106,7 +1121,9 @@ class StencilServer:
             try:
                 chaos.fire("serve.settle", shape=_shape_str(fb.shape),
                            device=fb.device)
-                jax.block_until_ready(fb.out)
+                with TraceAnnotation("stencil.serve.wait",
+                                     bucket_id=fb.bucket_id):
+                    jax.block_until_ready(fb.out)
             except Exception as e:
                 with self._lock:
                     self._inflight.remove(fb)
@@ -1125,14 +1142,12 @@ class StencilServer:
                 continue
             now = time.perf_counter()
             dt = now - fb.t0
-            with self._cv:
+            with TraceAnnotation("stencil.serve.book",
+                                 bucket_id=fb.bucket_id,
+                                 requests=len(fb.requests)), self._cv:
                 self._inflight.remove(fb)
-                warm = fb.entry.mark_ready(dt)
                 st = self.stats_
-                if warm:
-                    st.wall_s += dt
-                    st.warm_states += len(fb.requests)
-                else:
+                if not fb.entry.mark_ready(dt):
                     st.compile_wall_s += dt
                 st.batches += 1
                 st.padded_states += fb.bucket - len(fb.requests)
@@ -1205,14 +1220,16 @@ class StencilServer:
         never waits on a sweep.
         """
         with self._step_lock:
-            with self._lock:
-                before = self.stats_.requests
-                prior = list(self._inflight)
-            self._admit()
-            if self.async_dispatch:
-                self._settle(prior)
-            with self._lock:
-                return self.stats_.requests - before
+            self._turns += 1
+            with TraceAnnotation("stencil.serve.turn", turn=self._turns):
+                with self._lock:
+                    before = self.stats_.requests
+                    prior = list(self._inflight)
+                self._admit()
+                if self.async_dispatch:
+                    self._settle(prior)
+                with self._lock:
+                    return self.stats_.requests - before
 
     def flush(self) -> dict[int, jnp.ndarray]:
         """Step until nothing is pending or in flight; return every
@@ -1270,8 +1287,6 @@ class StencilServer:
             st = self.stats_
             s = dataclasses.asdict(st)
             lat = s.pop("latencies_s")
-            s["per_state_s"] = st.per_state_s
-            s["throughput_states_per_s"] = st.throughput
             s["latency"] = {
                 "count": len(lat),
                 "p50_s": st.p50_latency_s,
@@ -1399,8 +1414,7 @@ def main() -> None:
           f"{s['compile_wall_s'] * 1e3:.1f} ms first calls), warm pass "
           f"{warm * 1e3:.1f} ms -> "
           f"{args.requests / warm:.1f} states/s warm")
-    print(f"warm sweep wall per state {s['per_state_s'] * 1e6:.0f} us; "
-          f"latency p50 {s['latency']['p50_s'] * 1e3:.1f} ms / "
+    print(f"latency p50 {s['latency']['p50_s'] * 1e3:.1f} ms / "
           f"p95 {s['latency']['p95_s'] * 1e3:.1f} ms; "
           f"plan cache: {s['plan_cache']['hits']} hits / "
           f"{s['plan_cache']['misses']} misses "
