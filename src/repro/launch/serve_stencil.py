@@ -100,6 +100,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -124,12 +125,13 @@ __all__ = ["StencilServer", "ServeStats", "RequestShed", "SERVE_SPANS"]
 
 #: The profiler spans of the scheduler's turn, outermost first.  ``turn``
 #: wraps one ``step()`` (arg ``turn``, its sequence number); inside it
-#: ``stack`` (zero padding, ``jnp.stack``, ``device_put``), ``lookup``
-#: (the plan cache) and ``launch`` (the executable's dispatch) form one
-#: bucket, ``wait`` blocks on a bucket dispatched on an earlier turn and
-#: ``book`` files its results.  Each bucket's spans share the arg
-#: ``bucket_id``; ``launch`` lists its ``tickets``.  ``idle`` is the
-#: background stepper waiting for work, outside any turn.
+#: ``stack`` (one compiled stack-and-pad call, ``device_put``),
+#: ``lookup`` (the plan cache) and ``launch`` (the executable's dispatch)
+#: form one bucket, ``wait`` blocks on a bucket dispatched on an earlier
+#: turn and ``book`` splits it (one compiled call) and files its
+#: results.  Each bucket's spans share the arg ``bucket_id``; ``launch``
+#: lists its ``tickets``.  ``idle`` is the background stepper waiting for
+#: work, outside any turn.
 SERVE_SPANS = ("stencil.serve.turn", "stencil.serve.stack",
                "stencil.serve.lookup", "stencil.serve.launch",
                "stencil.serve.wait", "stencil.serve.book",
@@ -147,6 +149,27 @@ def _bucket(n: int, max_batch: int) -> int:
     while b < n and b < max_batch:
         b *= 2
     return min(b, max_batch)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _stack(bucket: int, *states):
+    """The live ``states`` stacked into a ``(bucket, *shape)`` batch whose
+    trailing ``bucket - len(states)`` slots are zeros: one compiled call
+    per (shape, dtype, bucket, live count)."""
+    batch = jnp.stack(states)
+    pad = bucket - len(states)
+    if pad:
+        batch = jnp.concatenate(
+            [batch, jnp.zeros((pad,) + batch.shape[1:], batch.dtype)])
+    return batch
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _split(batch, n: int) -> tuple:
+    """The first ``n`` states of a settled batch, one array each (the
+    padded slots never leave the program): one compiled call per (shape,
+    dtype, bucket, live count)."""
+    return tuple(batch[i] for i in range(n))
 
 
 def _shape_str(shape: tuple[int, ...]) -> str:
@@ -218,6 +241,7 @@ class _InFlight:
     t0: float                # dispatch time (perf_counter)
     device: int              # index into the server's device list
     bucket_id: int           # links the bucket's profiler spans
+    calls: int               # compiled calls made for it so far
     segment: Segment | None = None   # the rollout hop this bucket ran
 
 
@@ -231,6 +255,10 @@ class ServeStats:
     includes its queueing behind earlier buckets, so per-bucket wall
     clock double-counts.  Where a warm turn's time goes is read from the
     profiler spans ``SERVE_SPANS`` instead.
+
+    ``dispatches`` counts the compiled calls made for settled buckets:
+    a bucket of b > 1 states is one stack, one sweep and one split (3),
+    a lone state the sweep alone (1); no other array op runs in the turn.
 
     ``latencies_s`` records every request's submit -> settled latency
     (the queue + batching + device time a caller actually waits);
@@ -254,6 +282,7 @@ class ServeStats:
     requests: int = 0
     batches: int = 0
     padded_states: int = 0
+    dispatches: int = 0
     compile_wall_s: float = 0.0  # first-call (trace+compile+sweep) seconds
     deadline_misses: int = 0
     bucket_failures: int = 0
@@ -880,7 +909,11 @@ class StencilServer:
 
     def _dispatch_bucket(self, shape: tuple[int, ...], cap: int,
                          chunk: list[_Request]) -> _InFlight:
-        """Stack/pad one <= cap group on the host and launch it (async).
+        """Stack/pad one <= cap group and launch it (async).
+
+        A bucket of b > 1 is stacked and zero-padded by one compiled call
+        (:func:`_stack`); a lone state goes to the unbatched executable
+        as it is.
 
         Plain requests run the server's ``steps``-sweep executable; a
         rollout group (all members share the next-segment signature, by
@@ -904,11 +937,10 @@ class StencilServer:
                 self.stats_.rollout_attempts += len(chunk)
         with TraceAnnotation("stencil.serve.stack", bucket_id=bid, size=b,
                              shape=_shape_str(shape)):
-            states = [r.state for r in chunk]
-            states += [jnp.zeros(shape, jnp.dtype(self.dtype))] * (
-                b - len(chunk))
-            batch_arr = jnp.stack(states)
-            arg = batch_arr[0] if b == 1 else batch_arr
+            if b == 1:
+                arg, calls = chunk[0].state, 0
+            else:
+                arg, calls = _stack(b, *(r.state for r in chunk)), 1
             if mesh is not None:
                 lead = [None] if b > 1 else []
                 axes = [a if a else None for a in self.grid_axes]
@@ -939,7 +971,7 @@ class StencilServer:
             out = entry.dispatch(arg)
         return _InFlight(shape=shape, requests=list(chunk), bucket=b,
                          entry=entry, out=out, t0=t0, device=di,
-                         bucket_id=bid, segment=seg)
+                         bucket_id=bid, calls=calls + 1, segment=seg)
 
     def _salvage(self) -> None:
         """Settle whatever is in flight before propagating a primary
@@ -1144,65 +1176,73 @@ class StencilServer:
             dt = now - fb.t0
             with TraceAnnotation("stencil.serve.book",
                                  bucket_id=fb.bucket_id,
-                                 requests=len(fb.requests)), self._cv:
-                self._inflight.remove(fb)
-                st = self.stats_
-                if not fb.entry.mark_ready(dt):
-                    st.compile_wall_s += dt
-                st.batches += 1
-                st.padded_states += fb.bucket - len(fb.requests)
-                ds = self._device_stats[fb.device]
-                ds["batches"] += 1
-                ds["states"] += len(fb.requests)
-                # success resets the ladder counters for this group/device
-                self._dev_fail[fb.device] = 0
-                self._probation[fb.device] = False
-                self._dev_cooldown[fb.device] = self.evict_cooldown_s
-                self._group_failures[fb.shape] = 0
-                pol = self._retry.get(fb.shape)
-                if pol is not None:
-                    pol.on_success()
+                                 requests=len(fb.requests)):
                 # a rollout bucket's out is the program pytree
                 # (final, emits); the one-segment program's emit (if
-                # any) IS the final state
+                # any) IS the final state.  The split runs before
+                # the lock: booking below only assigns its parts
                 final = fb.out[0] if fb.segment is not None else fb.out
-                for i, r in enumerate(fb.requests):
-                    res = final if fb.bucket == 1 else final[i]
-                    if r.ticket in self._cancelled:
-                        # settle-then-drop: the bucket ran, the
-                        # cancelled ticket's share is discarded
-                        self._cancelled.discard(r.ticket)
-                        self._rollouts.pop(r.ticket, None)
-                        continue
-                    if r.rollout is not None:
-                        task = r.rollout
-                        if r.attempts > 1:
-                            # this segment settled only after a retry —
-                            # the serving mirror of RolloutResult.recovered
-                            st.rollout_recovered += 1
-                        task.seg += 1
-                        task.done_steps += fb.segment.steps
-                        if fb.segment.emit:
-                            # one-segment program: at most one emit, == res
-                            task.emits.append((task.done_steps, res))
-                        if not task.done:
-                            # requeue for the next segment, preserving the
-                            # submit clock (latency spans the whole
-                            # program) but with a fresh attempt count for
-                            # the next hop
-                            self._pending.append(dataclasses.replace(
-                                r, state=res, attempts=0))
+                if fb.bucket == 1:
+                    parts = (final,)
+                else:
+                    parts = _split(final, len(fb.requests))
+                    fb.calls += 1
+                with self._cv:
+                    self._inflight.remove(fb)
+                    st = self.stats_
+                    if not fb.entry.mark_ready(dt):
+                        st.compile_wall_s += dt
+                    st.batches += 1
+                    st.padded_states += fb.bucket - len(fb.requests)
+                    st.dispatches += fb.calls
+                    ds = self._device_stats[fb.device]
+                    ds["batches"] += 1
+                    ds["states"] += len(fb.requests)
+                    # success resets the ladder counters of group/device
+                    self._dev_fail[fb.device] = 0
+                    self._probation[fb.device] = False
+                    self._dev_cooldown[fb.device] = self.evict_cooldown_s
+                    self._group_failures[fb.shape] = 0
+                    pol = self._retry.get(fb.shape)
+                    if pol is not None:
+                        pol.on_success()
+                    for r, res in zip(fb.requests, parts):
+                        if r.ticket in self._cancelled:
+                            # settle-then-drop: the bucket ran, the
+                            # cancelled ticket's share is discarded
+                            self._cancelled.discard(r.ticket)
+                            self._rollouts.pop(r.ticket, None)
                             continue
-                    self._done[r.ticket] = res
-                    st.requests += 1
-                    lat = now - r.submit_t
-                    st.latencies_s.append(lat)
-                    if r.deadline_s is not None:
-                        miss = lat > r.deadline_s
-                        st.deadline_misses += miss
-                        self._deadline_window.append(int(miss))
-                    settled += 1
-                self._cv.notify_all()
+                        if r.rollout is not None:
+                            task = r.rollout
+                            if r.attempts > 1:
+                                # this segment settled only after a
+                                # retry — the serving mirror of
+                                # RolloutResult.recovered
+                                st.rollout_recovered += 1
+                            task.seg += 1
+                            task.done_steps += fb.segment.steps
+                            if fb.segment.emit:
+                                # one-segment program: one emit, == res
+                                task.emits.append((task.done_steps, res))
+                            if not task.done:
+                                # requeue for the next segment,
+                                # preserving the submit clock (latency
+                                # spans the whole program) but with a
+                                # fresh attempt count for the next hop
+                                self._pending.append(dataclasses.replace(
+                                    r, state=res, attempts=0))
+                                continue
+                        self._done[r.ticket] = res
+                        st.requests += 1
+                        lat = now - r.submit_t
+                        st.latencies_s.append(lat)
+                        if r.deadline_s is not None:
+                            miss = lat > r.deadline_s
+                            st.deadline_misses += miss
+                            self._deadline_window.append(int(miss))
+                        settled += 1
+                    self._cv.notify_all()
         if failure is not None:
             raise failure
         return settled

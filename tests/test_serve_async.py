@@ -2,6 +2,7 @@
 loop, step()-driven bucket formation, latency/deadline tracking,
 admission control at the batch-scaled VMEM cliff, deferred-device-error
 recovery (cold-executable accounting), and multi-device routing."""
+import collections
 import os
 import subprocess
 import sys
@@ -9,11 +10,13 @@ import sys
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro import api
 from repro.core import stencil_spec as ss
-from repro.core.plan_cache import PlanCache
+from repro.core.plan_cache import CachedExecutable, PlanCache
+from repro.launch import serve_stencil
 from repro.kernels.ref import stencil_ref
 
 from test_multidevice import run_with_devices
@@ -56,6 +59,107 @@ def test_async_dispatch_bit_exact_vs_sync_on_mixed_stream():
         assert st["padded_states"] == 0
         assert st["plan_cache"]["misses"] == 3
         assert st["latency"]["count"] == 7
+
+
+@pytest.mark.parametrize("kind,n,b", [
+    ("plain", 1, 1), ("plain", 2, 2), ("plain", 3, 4), ("plain", 4, 4),
+    ("rollout", 3, 4)],
+    ids=["b1", "b2", "b4-of-3", "b4", "rollout-b4-of-3"])
+def test_compiled_stack_and_split_bit_exact_vs_eager(kind, n, b):
+    """One bucket of n states, stacked and split by compiled calls: the
+    served results equal, bit for bit, the cache entry's own executable
+    run on the eagerly stacked, zero-padded batch and then sliced per
+    state; they match the per-state reference; no padded slot reaches a
+    result."""
+    spec = ss.star(2, 2, seed=1)
+    steps, shape = 3, (24, 24)
+    server = api.StencilServer(spec, steps, max_batch=4, backends=["jnp"],
+                               admission=False)
+    rng = np.random.default_rng(10 + n)
+    states = [rng.normal(size=shape).astype(np.float32) for _ in range(n)]
+    seg = api.Segment(steps, emit=True) if kind == "rollout" else None
+    tickets = [server.submit(s) if seg is None
+               else server.submit_rollout(s, [seg]) for s in states]
+    out = server.flush()
+    assert sorted(out) == tickets          # n results: none for a pad
+    st = server.stats()
+    assert st["batches"] == 1 and st["padded_states"] == b - n
+    # the entry the server ran (a hit, no new miss) on the eager batch
+    misses = server.cache.stats()["misses"]
+    kw = server._plan_kwargs(shape)
+    if seg is None:
+        entry = server.cache.get(server._problem(shape, b), **kw)
+    else:
+        entry = server.cache.get_program(api.RolloutProgram(
+            server._problem(shape, b, steps=steps), (seg,)), **kw)
+    assert server.cache.stats()["misses"] == misses
+    batch = jnp.stack([jnp.asarray(s) for s in states]
+                      + [jnp.zeros(shape, jnp.float32)] * (b - n))
+    ran = entry.dispatch(batch[0] if b == 1 else batch)
+    final = ran if seg is None else ran[0]
+    for i, (t, state) in enumerate(zip(tickets, states)):
+        want = np.asarray(final if b == 1 else final[i])
+        np.testing.assert_array_equal(np.asarray(out[t]), want)
+        np.testing.assert_allclose(np.asarray(out[t]),
+                                   _ref(state, spec, steps), atol=1e-4)
+        if seg is not None:
+            (at, emitted), = server.rollout_results(t)
+            assert at == steps
+            np.testing.assert_array_equal(np.asarray(emitted), want)
+
+
+def test_dispatches_count_compiled_calls_and_warm_pass_compiles_nothing(
+        monkeypatch):
+    """On a mixed stream every bucket of b > 1 makes three compiled
+    calls (stack, sweep, split) and a lone state one (the sweep), as
+    ``stats()["dispatches"]`` books; the stack and split programs are
+    cached per (shape, bucket, live count), so a second pass over the
+    same shapes compiles nothing."""
+    made = collections.Counter()
+    for name in ("_stack", "_split"):
+        def counted(*args, _fn=getattr(serve_stencil, name), _name=name):
+            made[_name] += 1
+            return _fn(*args)
+        monkeypatch.setattr(serve_stencil, name, counted)
+    sweep = CachedExecutable.dispatch
+
+    def dispatch(self, x):
+        made["sweep"] += 1
+        return sweep(self, x)
+    monkeypatch.setattr(CachedExecutable, "dispatch", dispatch)
+    compiles = collections.Counter()
+
+    def on_event(event, duration, **_):
+        if event.startswith("/jax/core/compile/"):
+            compiles[event] += 1
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    spec = ss.box(2, 1, seed=0)
+    server = api.StencilServer(spec, 2, max_batch=4, backends=["jnp"],
+                               admission=False)
+    rng = np.random.default_rng(12)
+    # buckets: 18x18 of 4 (one pad), 22x22 alone, 26x26 of 2, and 20x20
+    # of 4 then alone
+    shapes = [(18, 18)] * 3 + [(22, 22)] + [(26, 26)] * 2 + [(20, 20)] * 5
+    states = [rng.normal(size=s).astype(np.float32) for s in shapes]
+    try:
+        for attempt in range(2):
+            server.reset_stats()
+            made.clear()
+            compiles.clear()
+            outs = server.serve(states)
+            st = server.stats()
+            assert st["batches"] == 5 and st["padded_states"] == 1
+            assert made == {"_stack": 3, "sweep": 5, "_split": 3}
+            assert st["dispatches"] == 3 * 3 + 2 == sum(made.values())
+            if attempt == 0:
+                assert compiles, "the first pass compiles its buckets"
+            else:
+                assert not compiles, compiles
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    for state, out in zip(states, outs):
+        np.testing.assert_allclose(np.asarray(out), _ref(state, spec, 2),
+                                   atol=1e-4)
 
 
 def test_step_admits_newly_submitted_states_between_turns():
