@@ -10,8 +10,10 @@ produced with the reference (the program's reading) and compares the
 control, the reference computed at ``high`` (three bf16 passes) put in
 the program's place, with the same reference (the control's reading).
 One line per seed, then a JSON summary with the largest program reading
-and the smallest control reading.  The benchmark's own runs never run
-this; ``limits/<workload>.json`` records what it read.
+and the smallest control reading of each number the check returns.  It
+runs on a cell listed in ``BENCHMARK.json`` before the cell has a limit
+file; the benchmark's own runs never run this, and
+``limits/<workload>.json`` records what it read.
 """
 from __future__ import annotations
 
@@ -43,6 +45,25 @@ def readings(cell, seed: int, seconds: float, devices) -> dict:
     return {"seed": seed, "program": program, "control": control}
 
 
+def measure(cell, seeds, seconds: float, devices) -> tuple[list, dict]:
+    """The readings of every seed, each printed as it comes, and their
+    summary: for each number the path's check returns, the largest
+    program reading and the smallest control reading.  The cell needs no
+    limit file: these readings are what its limits are set from."""
+    rows = []
+    for seed in seeds:
+        row = readings(cell, seed, seconds, devices)
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    summary = {}
+    for name in rows[0]["program"]:
+        summary[name] = {
+            "program_max": max(r["program"][name] for r in rows),
+            "control_min": min(r["control"][name] for r in rows),
+            "seeds": len(rows)}
+    return rows, summary
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -56,17 +77,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     cell = run.Cell(args.workload)
     devices = run.chips(cell.chips)
-    rows = []
-    for seed in seed_list(args.seeds):
-        row = readings(cell, seed, args.seconds, devices)
-        rows.append(row)
-        print(json.dumps(row), flush=True)
-    summary = {}
-    for name in cell.limits["compared"]:
-        prog = [r["program"][name] for r in rows]
-        ctrl = [r["control"][name] for r in rows]
-        summary[name] = {"program_max": max(prog), "control_min": min(ctrl),
-                         "seeds": len(rows)}
+    _, summary = measure(cell, seed_list(args.seeds), args.seconds, devices)
     print(json.dumps({"workload": args.workload, "summary": summary}))
     return 0
 

@@ -37,23 +37,31 @@ def taps(coeffs) -> list[tuple[tuple[int, ...], float]]:
             for idx in zip(*np.nonzero(c))]
 
 
+def _bf16(v):
+    """``v`` rounded to bfloat16 and kept in float32.  ``reduce_precision``
+    is an op the compiler has to keep; the TPU compiler may fold a round
+    trip through ``astype`` away in part (on box3d_r1 at 512^3 the control
+    then read 4x the program, or a whole bf16 rounding, by how the sum
+    was written)."""
+    return lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+
 def _split_bf16(v):
-    hi = v.astype(jnp.bfloat16).astype(jnp.float32)
-    lo = (v - hi).astype(jnp.bfloat16).astype(jnp.float32)
-    return hi, lo
+    hi = _bf16(v)
+    return hi, _bf16(v - hi)
 
 
-def _product(c: float, x, precision: str):
-    c32 = jnp.float32(c)
-    if precision == "highest":
-        return c32 * x
-    ch, cl = _split_bf16(c32)
-    xh, xl = _split_bf16(x)
-    return ch * xh + ch * xl + cl * xh
+def _split_coeff(c: float) -> tuple[float, float]:
+    """The coefficient's two bfloat16 parts, on the host: constants."""
+    hi = np.float32(np.float32(c).astype(jnp.bfloat16))
+    lo = np.float32(np.float32(np.float32(c) - hi).astype(jnp.bfloat16))
+    return float(hi), float(lo)
 
 
 def _valid(xp, tp, r: int, precision: str):
     """Gather sum over a state padded by ``r`` on its trailing axes."""
+    if precision == "high":
+        return _valid_high(xp, tp, r)
     nd = len(tp[0][0])
     axes = range(xp.ndim - nd, xp.ndim)
     out = None
@@ -61,9 +69,29 @@ def _valid(xp, tp, r: int, precision: str):
         idx = [slice(None)] * xp.ndim
         for a, o in zip(axes, off):
             idx[a] = slice(r + o, xp.shape[a] - r + o)
-        term = _product(c, xp[tuple(idx)], precision)
+        term = jnp.float32(c) * xp[tuple(idx)]
         out = term if out is None else out + term
     return out
+
+
+def _valid_high(xp, tp, r: int):
+    """The gather sum at ``high``: the padded state is split once, and a
+    loop adds one tap a turn, in the same order, so that one partial sum
+    is live (an unrolled sum over both parts of 27 taps does not fit a
+    512^3 state on one chip)."""
+    nd = len(tp[0][0])
+    lead = xp.ndim - nd
+    size = xp.shape[:lead] + tuple(n - 2 * r for n in xp.shape[lead:])
+    xh, xl = _split_bf16(xp)
+    offs = jnp.asarray([[r + o for o in off] for off, _ in tp], jnp.int32)
+    cs = jnp.asarray([_split_coeff(c) for _, c in tp], jnp.float32)
+
+    def tap(i, out):
+        start = (0,) * lead + tuple(offs[i, k] for k in range(nd))
+        h = lax.dynamic_slice(xh, start, size)
+        lo = lax.dynamic_slice(xl, start, size)
+        return out + (cs[i, 0] * h + cs[i, 0] * lo + cs[i, 1] * h)
+    return lax.fori_loop(0, len(tp), tap, jnp.zeros(size, jnp.float32))
 
 
 def _edges(b, r: int, a: int, name: str | None, n: int):

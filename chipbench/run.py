@@ -83,17 +83,22 @@ def cell_metrics(bench: dict, workload: str, kind: str) -> list[dict]:
     """The ``end_to_end`` or ``per_layer`` metrics this cell reports."""
     e2e = {m["name"]: m for m in bench["end_to_end"]}
 
-    def applies(m):
+    def applies(m, kind):
         if "workloads" in m:
             return workload in m["workloads"]
         if kind == "per_layer":
-            return applies(e2e[m["moves"]])
+            return applies(e2e[m["moves"]], "end_to_end")
         return True
-    return [m for m in bench[kind] if applies(m)]
+    return [m for m in bench[kind] if applies(m, kind)]
 
 
 class Cell:
-    """Everything one run needs, found by the cell's name."""
+    """Everything one run needs, found by the cell's name.
+
+    ``limits`` is None until ``limits/<workload>.json`` exists:
+    ``control.py`` measures what it is set from, and a run refuses the
+    cell until then.
+    """
 
     def __init__(self, workload: str, bench: dict | None = None):
         self.bench = bench if bench is not None else load_benchmark()
@@ -102,7 +107,9 @@ class Cell:
         self.chips = int(self.entry["chips"])
         self.config = load_json("configs", self.entry["config"])
         self.traffic = load_json("traffic", self.entry["traffic"])
-        self.limits = load_json("limits", workload)
+        limits = HERE / "limits" / f"{workload}.json"
+        self.limits = (json.loads(limits.read_text()) if limits.is_file()
+                       else None)
         self.path = load_module("paths", self.traffic["path"])
 
 
@@ -288,6 +295,10 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
     try:
         cell = Cell(args.workload)
+        if cell.limits is None:
+            raise BenchError(f"no file chipbench/limits/{cell.name}.json: "
+                             f"chipbench/control.py measures the readings "
+                             f"its limits are set from")
         from repro.launch.compile_cache import enable_compile_cache
         enable_compile_cache()
         devices = chips(cell.chips)

@@ -4,7 +4,10 @@
 of the cell's path at a cut size (sweep: 2048², 16 calls; served: 30
 requests at 300/s), and ``data/<workload>.json``
 the window's facts.  The numbers pinned here were read from those traces
-when they were recorded.
+when they were recorded.  ``data/spans/`` holds the recordings of the
+program with its spans and kernel names (``record.py``): every cell that
+has one there is found by its file name, and every metric of the cell
+reads on it, each as its ``read`` pins it.
 """
 import json
 import pathlib
@@ -14,7 +17,11 @@ import pytest
 from chipbench import run, trace, work
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
+SPANS_DATA = DATA / "spans"
 PEAKS = work.peaks_for("TPU v5 lite")
+#: the cells that have a recording with spans
+RECORDED = sorted(p.name[:-len(".xplane.pb")]
+                  for p in SPANS_DATA.glob("*.xplane.pb"))
 
 
 def readings(name: str):
@@ -23,6 +30,14 @@ def readings(name: str):
     t = trace.load(str(DATA / f"{name}.xplane.pb"), cell.chips)
     r = run.Readings(cell, fx["facts"], t, {"compile_s": 1.25}, PEAKS)
     return cell, r
+
+
+def recorded(name: str):
+    """Like ``readings``, on the recordings with spans."""
+    cell = run.Cell(name)
+    fx = json.loads((SPANS_DATA / f"{name}.json").read_text())
+    t = trace.load(str(SPANS_DATA / f"{name}.xplane.pb"), cell.chips)
+    return run.Readings(cell, fx["facts"], t, {"compile_s": 1.25}, PEAKS)
 
 
 def test_classify_by_kind():
@@ -43,14 +58,17 @@ def test_interval_arithmetic():
     assert trace.length(trace.subtract([(0, 4)], [(0, 4)])) == 0
 
 
-@pytest.mark.parametrize("name", ["star2d_r2.sweep",
-                                  "star2d_r2.ensemble"])
+@pytest.mark.parametrize("name", RECORDED)
 def test_every_metric_of_the_cell_reads(name):
-    cell, r = readings(name)
-    got = run.read_per_layer(cell, r)
-    want = {m["name"] for m in run.cell_metrics(cell.bench, name,
+    r = recorded(name)
+    got = run.read_per_layer(r.cell, r)
+    want = {m["name"] for m in run.cell_metrics(r.cell.bench, name,
                                                 "per_layer")}
     assert set(got) == want
+    pinned = json.loads((SPANS_DATA / f"{name}.json").read_text())["read"]
+    assert pinned and set(pinned) <= want
+    for m, v in pinned.items():
+        assert got[m]["value"] == pytest.approx(v, rel=1e-9), m
     for m, v in got.items():
         if v["unit"] == "%":
             assert 0 < v["value"] <= 100, (m, v)

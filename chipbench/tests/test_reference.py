@@ -45,6 +45,24 @@ def test_high_precision_is_coarser():
     assert 1e-6 < err < 1e-3
 
 
+def test_high_rounds_to_bf16_in_the_compiled_program():
+    # the control's bf16 parts come from an op the compiler keeps, not from
+    # an astype round trip it may drop as a pair of converts
+    from repro.core.stencil_spec import PAPER_SUITE
+    c = PAPER_SUITE()["box3d_r1"].gather_coeffs
+    key = (c.shape, tuple(c.ravel().tolist()))
+    x = jax.ShapeDtypeStruct((8, 16, 128), jnp.float32)
+    hlo = reference._evolve_fn(key, 2, "high", None).lower(x).compile()
+    assert "reduce-precision(" in hlo.as_text()
+    v = jnp.asarray(np.random.default_rng(3).standard_normal(256),
+                    jnp.float32)
+    hi, lo = reference._split_bf16(v)
+    for part in (hi, lo):
+        np.testing.assert_array_equal(
+            part, part.astype(jnp.bfloat16).astype(jnp.float32))
+    assert float(jnp.max(jnp.abs(v - hi - lo) / jnp.abs(v))) < 2**-16
+
+
 def test_sharded_evolve_matches_one_device():
     # runs in a child with four host devices; the parent's JAX has one
     import subprocess
@@ -59,10 +77,12 @@ def test_sharded_evolve_matches_one_device():
         mesh = jax.make_mesh((2, 2), ("a", "b"))
         sh = NamedSharding(mesh, P("a", "b"))
         x = jnp.asarray(np.random.default_rng(2).standard_normal((64, 96)), jnp.float32)
-        one = reference.evolve(x, c, 6)
-        many = reference.evolve(jax.device_put(x, sh), c, 6, sharding=sh)
-        assert many.sharding == sh
-        assert float(jnp.max(jnp.abs(one - many))) < 1e-6
+        for prec in ("highest", "high"):
+            one = reference.evolve(x, c, 6, prec)
+            many = reference.evolve(jax.device_put(x, sh), c, 6, prec,
+                                    sharding=sh)
+            assert many.sharding == sh
+            assert float(jnp.max(jnp.abs(one - many))) < 1e-6
         print("ok")
     """ % (str(run.ROOT / "src"), str(run.ROOT)))
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=4",

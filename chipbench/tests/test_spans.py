@@ -7,42 +7,28 @@ v5e by ``record.py``, at the cut sizes of ``data/`` (the recordings
 ``test_reducers.py`` reads, made before the program had spans or kernel
 names); the numbers pinned here were read from them then.
 """
-import json
-import pathlib
-
 import pytest
 
 from chipbench import reduce, run, spans, trace
-from chipbench.tests.test_reducers import PEAKS, readings
+from chipbench.tests.test_reducers import (PEAKS, RECORDED, readings,
+                                           recorded)
 
-SPANS_DATA = pathlib.Path(__file__).resolve().parent / "data" / "spans"
 MS = 1e6                                  # ns
 HOST_TURN_MS = 4.9360714
 IDLE_IN_TURN = 67.727651
 
 
-def recorded(name: str):
-    """Like ``test_reducers.readings``, on the recordings with spans."""
-    cell = run.Cell(name)
-    fx = json.loads((SPANS_DATA / f"{name}.json").read_text())
-    t = trace.load(str(SPANS_DATA / f"{name}.xplane.pb"), cell.chips)
-    return run.Readings(cell, fx["facts"], t, {"compile_s": 1.25}, PEAKS)
-
-
-@pytest.mark.parametrize("name", ["star2d_r2.sweep",
-                                  "star2d_r2.ensemble"])
+@pytest.mark.parametrize("name", RECORDED)
 def test_every_metric_of_the_cell_reads_with_spans(name):
+    # the span readers read where the server's spans are, and the device
+    # idles in the turn's host work no longer than in the whole window
     r = recorded(name)
-    got = run.read_per_layer(r.cell, r)
-    want = {m["name"] for m in run.cell_metrics(r.cell.bench, name,
-                                                "per_layer")}
-    assert set(got) == want
-    for m, v in got.items():
-        if v["unit"] == "%":
-            assert 0 < v["value"] <= 100, (m, v)
-    if "idle_in_turn.serve" in got:
-        assert (got["idle_in_turn.serve"]["value"]
-                <= got["idle_share.serve"]["value"])
+    if spans.launches(r.trace):
+        assert spans.host_turn_ms(r) > 0
+        assert 0 <= spans.idle_in_turn(r) <= reduce.idle_share(r)
+    else:
+        assert spans.host_turn_ms(r) is None
+        assert spans.idle_in_turn(r) is None
 
 
 @pytest.mark.parametrize("name", ["star2d_r2.sweep",
