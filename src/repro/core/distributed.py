@@ -41,7 +41,14 @@ from repro.runtime.chaos import FaultError
 
 __all__ = ["halo_exchange", "distributed_stencil_step",
            "distributed_fused_chunk", "make_distributed_stepper",
-           "make_fused_distributed_stepper", "DistributedStepper"]
+           "make_fused_distributed_stepper", "DistributedStepper",
+           "INTERIOR_STEP", "INTERIOR_SWEEP"]
+
+#: kernel names of the overlap's interior recompute (a one-step or
+#: operator-fused core, an in-kernel sweep core), so that a profiler trace
+#: tells it from the chunk's own ``stencil_step`` / ``stencil_sweep``
+INTERIOR_STEP = "stencil_step_interior"
+INTERIOR_SWEEP = "stencil_sweep_interior"
 
 
 def _exchange_axis(block: jnp.ndarray, axis: int, r: int, mesh_axis: str,
@@ -186,6 +193,7 @@ def _zero_boundary_strips(y: jnp.ndarray, haloed: jnp.ndarray, *, t: int,
 
 def distributed_fused_chunk(block: jnp.ndarray, *, t: int,
                             base_core: Callable, fused_core: Callable,
+                            interior_core: Callable,
                             spec: StencilSpec, mesh_axes: dict[int, str],
                             periodic: bool = True,
                             overlap: bool = True) -> jnp.ndarray:
@@ -194,6 +202,8 @@ def distributed_fused_chunk(block: jnp.ndarray, *, t: int,
     The fused operator (order ``t*r``) is applied to the deep-haloed block;
     with ``overlap=True`` the halo-independent interior is expressed
     separately so XLA hides the permute latency behind interior MXU work.
+    ``interior_core`` computes that interior: ``fused_core``'s arithmetic,
+    its kernel named apart.
     Dirichlet-0 edge strips are fixed up per-step-exactly (``t > 1`` only —
     for a single step zero-extension IS per-step clamping).
 
@@ -216,7 +226,7 @@ def distributed_fused_chunk(block: jnp.ndarray, *, t: int,
         # halos stripped; unsharded axes keep their cheap local pads), exact
         # for points at distance >= t*r from the sharded local boundary.
         inner_in = _pad_local_axes(block, w, spec.ndim, mesh_axes, periodic)
-        interior = fused_core(inner_in)  # shrinks SHARDED axes by 2*t*r
+        interior = interior_core(inner_in)  # shrinks SHARDED axes by 2*t*r
         index = [slice(None)] * block.ndim
         for axis in mesh_axes:
             index[axis] = slice(w, block.shape[axis] - w)
@@ -245,7 +255,9 @@ def distributed_stencil_step(block: jnp.ndarray, *, engine: StencilEngine,
     return distributed_fused_chunk(block, t=1, base_core=core,
                                    fused_core=core, spec=engine.plan.spec,
                                    mesh_axes=mesh_axes, periodic=periodic,
-                                   overlap=overlap)
+                                   overlap=overlap,
+                                   interior_core=engine.named_core(
+                                       INTERIOR_STEP))
 
 
 class DistributedStepper:
@@ -365,17 +377,22 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
     base = StencilEngine(spec, option=option, backend=backend, block=block,
                          boundary="valid")
     depth_max = max(schedule) if schedule else 1
+    # per depth, the chunk's core over the haloed block and the interior
+    # recompute's core: the same arithmetic, the kernel named apart
     cores: dict[int, Callable] = {1: base._core}
+    interiors: dict[int, Callable] = {1: base.named_core(INTERIOR_STEP)}
     for t in sorted(set(schedule)):
         if t > 1:
             if fuse_strategy == "inkernel":
                 cores[t] = base.inkernel_core(t)
+                interiors[t] = base.inkernel_core(t, name=INTERIOR_SWEEP)
                 continue
             opt = fused_option if t == depth_max else "auto"
             fused = StencilEngine(temporal.fuse_steps(spec, t), option=opt,
                                   backend=backend, block=base.plan.block,
                                   boundary="valid")
             cores[t] = fused._core
+            interiors[t] = fused.named_core(INTERIOR_STEP)
 
     grid_axes = tuple(grid_axes)
     lead = 0 if batch is None else 1
@@ -388,7 +405,8 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
             b = distributed_fused_chunk(b, t=t, base_core=cores[1],
                                         fused_core=cores[t], spec=spec,
                                         mesh_axes=mesh_axes,
-                                        periodic=periodic, overlap=overlap)
+                                        periodic=periodic, overlap=overlap,
+                                        interior_core=interiors[t])
         return b
 
     sharded = jax.shard_map(local_fn, mesh=mesh, in_specs=pspec,

@@ -156,8 +156,10 @@ def register_backend(name: str, builder: Callable, *,
                      overwrite: bool = False) -> Backend:
     """Register a stencil execution backend.
 
-    ``builder(plan) -> core`` must return a valid-mode update (shrinks
-    each spatial axis by ``2 * plan.spec.order``).  Registration is
+    ``builder(plan, **opts) -> core`` must return a valid-mode update
+    (shrinks each spatial axis by ``2 * plan.spec.order``); ``opts`` may
+    carry ``name``, the name a kernel-backed core gives its kernel (the
+    others ignore it).  Registration is
     the extension point third-party kernels use — the engine and the
     planner both dispatch through this table, so a registered backend is
     automatically enumerated, priced (``mxu_efficiency`` modelled fraction
@@ -172,8 +174,8 @@ def register_backend(name: str, builder: Callable, *,
     axis by ``2*steps*order``); registering one makes the backend eligible
     for the planner's ``fuse_strategy="inkernel"`` candidates.  ``opts``
     carries the VMEM ``scratch`` policy
-    (``temporal.SCRATCH_MODES``) — accept ``**opts`` so new options stay
-    backward-compatible.
+    (``temporal.SCRATCH_MODES``) and may carry ``name`` as above — accept
+    ``**opts`` so new options stay backward-compatible.
 
     Raises ``ValueError`` on duplicate names unless ``overwrite=True``.
     """
@@ -214,15 +216,14 @@ def _codegen_builder(plan: StencilPlan, **_opts) -> Callable:
     return generate_update(plan).fn
 
 
-def _pallas_builder(plan: StencilPlan, **_opts) -> Callable:
+def _pallas_builder(plan: StencilPlan, **opts) -> Callable:
     from repro.kernels import ops as kops
-    return kops.pallas_backend_core(plan)
+    return kops.pallas_backend_core(plan, **opts)
 
 
-def _pallas_sweep_builder(plan: StencilPlan, steps: int, *,
-                          scratch: str = "pingpong", **_opts) -> Callable:
+def _pallas_sweep_builder(plan: StencilPlan, steps: int, **opts) -> Callable:
     from repro.kernels import ops as kops
-    return kops.pallas_sweep_core(plan, steps, scratch=scratch)
+    return kops.pallas_sweep_core(plan, steps, **opts)
 
 
 # separable factors the CONSTANT Toeplitz operator through its SVD and
@@ -278,10 +279,13 @@ class StencilEngine:
         # compiled-core caches: keys carry EVERY argument that changes the
         # built core beyond the engine's own frozen plan — fused_engine
         # keys the depth (the cover option is compatibility-checked and
-        # rebuilt on mismatch), inkernel_core keys (depth, scratch policy).
+        # rebuilt on mismatch), inkernel_core keys (depth, scratch policy,
+        # kernel name), named_core keys the kernel name.
         # A new knob must join the key, never alias an existing entry.
         self._fused_engines: dict[int, "StencilEngine"] = {}
-        self._inkernel_cores: dict[tuple[int, str], Callable] = {}
+        self._named_cores: dict[str, Callable] = {}
+        self._inkernel_cores: dict[tuple[int, str, str | None],
+                                   Callable] = {}
 
     @classmethod
     def from_execution_plan(cls, eplan) -> "StencilEngine":
@@ -291,14 +295,25 @@ class StencilEngine:
                    boundary=eplan.problem["boundary"])
 
     # -- construction -------------------------------------------------------
-    def _build_core(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    def _build_core(self, **opts) -> Callable[[jnp.ndarray], jnp.ndarray]:
         """The valid-mode update via the backend registry; boundary handling
         is layered on by :func:`repro.core.halo.wrap_boundary`."""
         backend = get_backend(self.plan.backend)
         if not backend.supports(self.plan.spec):
             raise ValueError(f"backend {backend.name!r} does not support "
                              f"{self.plan.spec.describe()}")
-        return backend.builder(self.plan)
+        return backend.builder(self.plan, **opts)
+
+    def named_core(self, name: str) -> Callable[[jnp.ndarray], jnp.ndarray]:
+        """The valid-mode update again, its kernel named ``name`` (cached).
+
+        The same arithmetic as ``_core``; a kernel-backed core compiles
+        the same kernel under another name, so a profiler trace tells a
+        second use of it apart (a backend with no kernel ignores it)."""
+        core = self._named_cores.get(name)
+        if core is None:
+            core = self._named_cores[name] = self._build_core(name=name)
+        return core
 
     # -- execution -----------------------------------------------------------
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -510,7 +525,8 @@ class StencilEngine:
         """Whether this engine's backend registers an in-kernel sweep."""
         return get_backend(self.plan.backend).sweep_builder is not None
 
-    def inkernel_core(self, t: int, scratch: str | None = None
+    def inkernel_core(self, t: int, scratch: str | None = None,
+                      name: str | None = None
                       ) -> Callable[[jnp.ndarray], jnp.ndarray]:
         """The backend's t-step in-kernel temporal-blocking core (cached).
 
@@ -520,10 +536,11 @@ class StencilEngine:
         protocol drive either interchangeably.  ``scratch`` overrides the
         engine's VMEM intermediate policy for this core; it is part of
         the cache key (a "single" core and a "pingpong" core compile
-        differently and must never alias).
+        differently and must never alias).  ``name`` renames the kernel
+        (None: the backend's own name); it is part of the key too.
         """
         scratch = temporal.check_scratch(scratch or self.scratch)
-        key = (t, scratch)
+        key = (t, scratch, name)
         core = self._inkernel_cores.get(key)
         if core is None:
             be = get_backend(self.plan.backend)
@@ -531,7 +548,8 @@ class StencilEngine:
                 raise ValueError(
                     f"backend {self.plan.backend!r} registers no "
                     f"sweep_builder; fuse_strategy='inkernel' needs one")
-            core = be.sweep_builder(self.plan, t, scratch=scratch)
+            opts = {} if name is None else {"name": name}
+            core = be.sweep_builder(self.plan, t, scratch=scratch, **opts)
             self._inkernel_cores[key] = core
         return core
 

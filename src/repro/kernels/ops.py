@@ -35,18 +35,19 @@ __all__ = ["stencil_matrixized", "stencil_sweep_matrixized", "banded_mix",
            "pallas_backend_core", "pallas_sweep_core", "resolve_interpret"]
 
 
-def pallas_backend_core(plan):
+def pallas_backend_core(plan, *, name: str = "stencil_step"):
     """Valid-mode core for the engine/planner backend registry.
 
     ``plan`` is a :class:`repro.core.engine.StencilPlan`; the returned
     callable is the registry contract (shrinks each spatial axis by
-    ``2 * spec.order``) backed by the Pallas MXU kernel.
+    ``2 * spec.order``) backed by the Pallas MXU kernel, named ``name``.
     """
     return functools.partial(stencil_matrixized, spec=plan.spec,
-                             cover=plan.cover, block=plan.block)
+                             cover=plan.cover, block=plan.block, name=name)
 
 
-def pallas_sweep_core(plan, steps: int, *, scratch: str = "pingpong"):
+def pallas_sweep_core(plan, steps: int, *, scratch: str = "pingpong",
+                      name: str = "stencil_sweep"):
     """Valid-mode T-step core (the registry's ``sweep_builder`` contract).
 
     Advances ``steps`` applications of ``plan.spec`` per call via the
@@ -54,7 +55,8 @@ def pallas_sweep_core(plan, steps: int, *, scratch: str = "pingpong"):
     ``2 * steps * spec.order``, exactly like the ``steps``-fused operator's
     core, so the halo layer and the distributed deep-halo protocol drive it
     unchanged.  ``scratch`` picks the VMEM intermediate policy
-    (``"pingpong"`` double buffer | ``"single"`` half the residency).
+    (``"pingpong"`` double buffer | ``"single"`` half the residency);
+    ``name`` is the kernel's name in the compiled program.
 
     The engine hands this core pre-padded arrays and drives it at
     ``boundary="valid"``, so for varying/masked specs the TRUE boundary is
@@ -64,7 +66,7 @@ def pallas_sweep_core(plan, steps: int, *, scratch: str = "pingpong"):
     return functools.partial(stencil_sweep_matrixized, spec=plan.spec,
                              steps=steps, cover=plan.cover, block=plan.block,
                              scratch=scratch,
-                             aux_boundary=plan.boundary)
+                             aux_boundary=plan.boundary, name=name)
 
 
 def _center_slice(f: np.ndarray, out_sizes) -> np.ndarray:
@@ -203,8 +205,9 @@ def stencil_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
                        block: tuple[int, ...] | None = None,
                        option: str = "parallel",
                        boundary: str = "valid",
-                       interpret: bool | None = None) -> jnp.ndarray:
-    """Stencil via the Pallas MXU kernel. Batch axes lead.
+                       interpret: bool | None = None,
+                       name: str = "stencil_step") -> jnp.ndarray:
+    """Stencil via the Pallas MXU kernel, named ``name``. Batch axes lead.
 
     ``boundary`` uses the shared halo layer: 'valid' (default) shrinks the
     spatial extent by ``spec.order`` per side; 'zero'/'periodic' pad first
@@ -227,7 +230,7 @@ def stencil_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
         xs = _pad_to_multiple(x, block, spec.order, spec.ndim)
         plan = stencil_mxu.build_kernel_plan(spec, cover, block)
         out = stencil_mxu.stencil_pallas_call(xs, plan, interpret=interpret,
-                                              aux=aux)
+                                              aux=aux, name=name)
         return out[tuple(slice(0, s) for s in out_sizes)]
     if batch == 0:   # empty batch: the old vmap path returned empty too
         return jnp.zeros(lead + out_sizes, x.dtype)
@@ -242,7 +245,7 @@ def stencil_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
     def call(xc, b):
         plan = stencil_mxu.build_kernel_plan(spec, cover, block, batch=b)
         return stencil_mxu.stencil_pallas_call(xc, plan, interpret=interpret,
-                                               aux=aux)
+                                               aux=aux, name=name)
 
     chunk = _feasible_fold(batch, lambda c: mx.batched_vmem_bytes(
         block, spec.order, x.dtype.itemsize, c))
@@ -259,7 +262,8 @@ def stencil_sweep_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
                              boundary: str = "valid",
                              interpret: bool | None = None,
                              scratch: str = "pingpong",
-                             aux_boundary: str | None = None) -> jnp.ndarray:
+                             aux_boundary: str | None = None,
+                             name: str = "stencil_sweep") -> jnp.ndarray:
     """``steps`` stencil applications in ONE in-kernel temporally-blocked
     pass (paper §6 x §4.3).  Batch axes lead (folded into the kernel's
     batch dimension — one launch, shared per-step band operands).
@@ -269,7 +273,8 @@ def stencil_sweep_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
     pad the deep halo once and preserve shape ('zero' is the zero-EXTENDED
     evolution — the engine splices per-step-exact strips on top, exactly as
     it does for operator fusion).  ``scratch`` picks the VMEM intermediate
-    policy ("pingpong" double buffer | "single" half the residency).
+    policy ("pingpong" double buffer | "single" half the residency);
+    ``name`` is the kernel's name in the compiled program.
 
     Varying/masked specs re-read their fields at every in-kernel step; the
     field is extended to the deep-halo slab with ``aux_boundary`` (defaults
@@ -308,7 +313,7 @@ def stencil_sweep_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
         plan = stencil_mxu.build_sweep_kernel_plan(spec, cover, block, steps,
                                                    scratch=scratch)
         out = stencil_mxu.sweep_pallas_call(xs, plan, interpret=interpret,
-                                            aux=aux)
+                                            aux=aux, name=name)
         return out[tuple(slice(0, s) for s in out_sizes)]
     if batch == 0:   # empty batch: the old vmap path returned empty too
         return jnp.zeros(lead + out_sizes, x.dtype)
@@ -321,7 +326,7 @@ def stencil_sweep_matrixized(x: jnp.ndarray, *, spec: StencilSpec,
         plan = stencil_mxu.build_sweep_kernel_plan(
             spec, cover, block, steps, batch=b, scratch=scratch)
         return stencil_mxu.sweep_pallas_call(xc, plan, interpret=interpret,
-                                             aux=aux)
+                                             aux=aux, name=name)
 
     chunk = _feasible_fold(batch, lambda c: mx.inkernel_vmem_bytes(
         block, steps, spec.order, x.dtype.itemsize, cover=cover, batch=c,

@@ -52,6 +52,10 @@ grid leaves idle.
 Each ``pallas_call`` carries a stable ``name`` (``stencil_step``,
 ``stencil_sweep``) with no shape or depth in it: the compiled custom
 call takes that name, so a profiler trace tells the two kernels apart.
+A caller may rename a call (``name=``) where the same kernel does a
+different job: the distributed stepper's interior recompute runs as
+``stencil_sweep_interior`` / ``stencil_step_interior``
+(``core/distributed.py``).
 """
 from __future__ import annotations
 
@@ -418,7 +422,8 @@ def _copy_window(src_hbm, dst, sem, block) -> None:
 
 def stencil_pallas_call(x: jnp.ndarray, plan: KernelPlan,
                         interpret: bool | None = None,
-                        aux: Sequence[jnp.ndarray] = ()) -> jnp.ndarray:
+                        aux: Sequence[jnp.ndarray] = (),
+                        name: str = "stencil_step") -> jnp.ndarray:
     """Run the matrixized stencil kernel over a haloed spatial array.
 
     ``x``: (S_0 + 2r, ..., S_{d-1} + 2r) haloed input; returns (S_0, ...,
@@ -469,7 +474,7 @@ def stencil_pallas_call(x: jnp.ndarray, plan: KernelPlan,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
-        name="stencil_step",
+        name=name,
     )(x, *t_inputs, *aux_inputs)
 
 
@@ -610,7 +615,8 @@ def _make_sweep_kernel(plan: SweepKernelPlan, out_dtype,
 
 def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
                       interpret: bool | None = None,
-                      aux: Sequence[jnp.ndarray] = ()) -> jnp.ndarray:
+                      aux: Sequence[jnp.ndarray] = (),
+                      name: str = "stencil_sweep") -> jnp.ndarray:
     """Advance a haloed spatial array by ``plan.steps`` base steps in-kernel.
 
     ``x``: (S_0 + 2*T*r, ..., S_{d-1} + 2*T*r) haloed input; returns
@@ -678,5 +684,5 @@ def sweep_pallas_call(x: jnp.ndarray, plan: SweepKernelPlan,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
-        name="stencil_sweep",
+        name=name,
     )(x, *t_inputs, *aux_inputs)

@@ -101,9 +101,9 @@ def test_inkernel_single_scratch_bit_exact_both_modes():
                                    np.asarray(seq), **REORDER_TOL,
                                    err_msg=scratch)
         # per-call override keys separately from the engine default
-        assert (2, scratch) in eng._inkernel_cores
+        assert (2, scratch, None) in eng._inkernel_cores
         eng.inkernel_core(2, "single")
-        assert (2, "single") in eng._inkernel_cores
+        assert (2, "single", None) in eng._inkernel_cores
     np.testing.assert_allclose(np.asarray(outs["pingpong"]),
                                np.asarray(outs["single"]), **REORDER_TOL)
     # modelled residency: one buffer instead of two
@@ -335,7 +335,7 @@ def test_sweep_fn_inkernel_is_jit_safe():
     fn = eng.sweep_fn(6, fuse=3, grid=(24, 24), strategy="inkernel")
     # the core cache keys (depth, scratch policy) — everything that
     # changes the compiled core
-    assert (3, "pingpong") in eng._inkernel_cores, \
+    assert (3, "pingpong", None) in eng._inkernel_cores, \
         "inkernel core was not pre-built"
     f = jax.jit(fn)
     out = f(x)

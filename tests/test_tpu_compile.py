@@ -113,6 +113,42 @@ def test_scenario_aux_operands_compile(one_chip):
         interpret=False), grid, one_chip)
 
 
+def test_mesh_cell_stepper_compiles_and_fits(topo, monkeypatch):
+    """The ``star2d_r2_49k`` deployment at full size: 49152² on the (2,2)
+    mesh through ``api.plan`` -> ``api.compile``.  The chunk's kernel and
+    the interior recompute's carry their own names, and a chip holds the
+    arguments, the output, the temporaries and one more queued output
+    (two calls in flight) in its 16 GiB."""
+    import json
+    import pathlib
+    from jax.sharding import NamedSharding
+    from repro import api
+    from repro.kernels import stencil_mxu
+    from repro.launch.mesh import make_mesh
+    cfg = json.loads((pathlib.Path(__file__).parents[1] / "chipbench"
+                      / "configs" / "star2d_r2_49k.json").read_text())
+    m = cfg["mesh"]
+    mesh = make_mesh(m["shape"], m["axes"], devices=topo.devices[:4])
+    problem = api.StencilProblem(SUITE[cfg["suite"]], tuple(cfg["grid"]),
+                                 dtype=cfg["dtype"], boundary=cfg["boundary"],
+                                 steps=16, mesh=mesh,
+                                 grid_axes=tuple(m["grid_axes"]))
+    monkeypatch.setattr(stencil_mxu, "resolve_interpret",
+                        lambda interpret=None: False)
+    run = api.compile(api.plan(problem), mesh=mesh)
+    x = jax.ShapeDtypeStruct(tuple(cfg["grid"]), jnp.float32,
+                             sharding=NamedSharding(mesh, run.stepper.pspec))
+    compiled = run.stepper.fn.lower(x).compile()
+    text = compiled.as_text()
+    assert "%stencil_sweep." in text and "%stencil_sweep_interior." in text
+    mem = compiled.memory_analysis()
+    shard = 4 * int(np.prod(cfg["grid"])) // mesh.devices.size  # f32
+    assert mem.output_size_in_bytes == shard
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + shard)
+    assert held <= 16 * 2**30, held / 2**30
+
+
 @pytest.mark.parametrize("depthwise", [False, True])
 def test_banded_mixer_compiles(one_chip, depthwise):
     """The sequence mixer shares the stencil kernels' window layout: a
