@@ -3,8 +3,10 @@
 The problem declares the mesh; the planner picks cover x backend x fuse
 depth by roofline model and records every decision; compile() emits the
 fused sharded stepper — ONE ``T*r``-deep halo exchange per fused chunk
-(collective-permutes counted below), interior update overlapped with the
-wire time (DESIGN.md §Planner).
+(collective-permutes counted below), then one sweep of the haloed block.
+The exchange is not overlapped with compute: on a TPU v5e (2,2) mesh at
+24576² a chip its collectives take 0.3–0.4 ms of device time a 16-step
+call, against 0.58 s of sweep kernel (DESIGN.md §Planner).
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python examples/pde_halo_exchange.py

@@ -5,11 +5,14 @@ scales out unchanged: each device owns a block of the grid, halos are the
 inter-device analogue of the overlapping BlockSpec windows, and the exchange
 is two ``lax.ppermute`` pairs per axis under ``shard_map``.
 
-Compute/communication overlap: the update is split into an *interior* region
-(needs no halo) and boundary strips (need it).  The permutes are issued
-first; XLA's async collectives then overlap the interior matmuls with the
-wire time — the schedule is visible in the compiled HLO
-(collective-permute-start ... interior dots ... collective-permute-done).
+No compute/communication overlap: a chunk exchanges its halo, then
+sweeps the haloed block once, so every output point is computed exactly
+once and the exchange's wire time is exposed.  It is small beside the
+sweep: on a TPU v5e (2,2) mesh at 24576² a chip, the 16 ``ppermute``s of
+a 16-step call take 0.3–0.4 ms of device time against 0.58 s of sweep
+kernel.  Splitting off a halo-free interior to hide it would recompute
+(or copy) the whole shard per chunk, which grows with the shard's area
+while the wire grows with its edge.
 
 Fused distributed sweeps (DESIGN.md §Planner): a chunk of ``T`` steps
 exchanges ONE ``T*r``-deep halo and then applies the T-fold self-correlated
@@ -41,14 +44,7 @@ from repro.runtime.chaos import FaultError
 
 __all__ = ["halo_exchange", "distributed_stencil_step",
            "distributed_fused_chunk", "make_distributed_stepper",
-           "make_fused_distributed_stepper", "DistributedStepper",
-           "INTERIOR_STEP", "INTERIOR_SWEEP"]
-
-#: kernel names of the overlap's interior recompute (a one-step or
-#: operator-fused core, an in-kernel sweep core), so that a profiler trace
-#: tells it from the chunk's own ``stencil_step`` / ``stencil_sweep``
-INTERIOR_STEP = "stencil_step_interior"
-INTERIOR_SWEEP = "stencil_sweep_interior"
+           "make_fused_distributed_stepper", "DistributedStepper"]
 
 
 def _exchange_axis(block: jnp.ndarray, axis: int, r: int, mesh_axis: str,
@@ -193,17 +189,12 @@ def _zero_boundary_strips(y: jnp.ndarray, haloed: jnp.ndarray, *, t: int,
 
 def distributed_fused_chunk(block: jnp.ndarray, *, t: int,
                             base_core: Callable, fused_core: Callable,
-                            interior_core: Callable,
                             spec: StencilSpec, mesh_axes: dict[int, str],
-                            periodic: bool = True,
-                            overlap: bool = True) -> jnp.ndarray:
+                            periodic: bool = True) -> jnp.ndarray:
     """Advance a local block by ``t`` steps with ONE ``t*r`` halo exchange.
 
-    The fused operator (order ``t*r``) is applied to the deep-haloed block;
-    with ``overlap=True`` the halo-independent interior is expressed
-    separately so XLA hides the permute latency behind interior MXU work.
-    ``interior_core`` computes that interior: ``fused_core``'s arithmetic,
-    its kernel named apart.
+    The fused operator (order ``t*r``) is applied once to the deep-haloed
+    block, so each output point is computed exactly once.
     Dirichlet-0 edge strips are fixed up per-step-exactly (``t > 1`` only —
     for a single step zero-extension IS per-step clamping).
 
@@ -221,17 +212,6 @@ def distributed_fused_chunk(block: jnp.ndarray, *, t: int,
     haloed = _haloed_input(block, w, spec.ndim, mesh_axes, periodic)
     full = fused_core(haloed)
 
-    if overlap and all(block.shape[a] > 2 * w for a in mesh_axes):
-        # Interior: fused update from locally-available data only (sharded
-        # halos stripped; unsharded axes keep their cheap local pads), exact
-        # for points at distance >= t*r from the sharded local boundary.
-        inner_in = _pad_local_axes(block, w, spec.ndim, mesh_axes, periodic)
-        interior = interior_core(inner_in)  # shrinks SHARDED axes by 2*t*r
-        index = [slice(None)] * block.ndim
-        for axis in mesh_axes:
-            index[axis] = slice(w, block.shape[axis] - w)
-        full = full.at[tuple(index)].set(interior)
-
     if not periodic and t > 1:
         full = _zero_boundary_strips(full, haloed, t=t, r=r,
                                      base_core=base_core,
@@ -241,8 +221,8 @@ def distributed_fused_chunk(block: jnp.ndarray, *, t: int,
 
 
 def distributed_stencil_step(block: jnp.ndarray, *, engine: StencilEngine,
-                             mesh_axes: dict[int, str], periodic: bool = True,
-                             overlap: bool = True) -> jnp.ndarray:
+                             mesh_axes: dict[int, str],
+                             periodic: bool = True) -> jnp.ndarray:
     """One sharded stencil step on a local block (inside shard_map).
 
     Single-step case of :func:`distributed_fused_chunk`; spatial axes left
@@ -254,10 +234,7 @@ def distributed_stencil_step(block: jnp.ndarray, *, engine: StencilEngine,
     core = engine._core
     return distributed_fused_chunk(block, t=1, base_core=core,
                                    fused_core=core, spec=engine.plan.spec,
-                                   mesh_axes=mesh_axes, periodic=periodic,
-                                   overlap=overlap,
-                                   interior_core=engine.named_core(
-                                       INTERIOR_STEP))
+                                   mesh_axes=mesh_axes, periodic=periodic)
 
 
 class DistributedStepper:
@@ -338,8 +315,8 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
                                    boundary: str = "periodic",
                                    block: tuple[int, ...] | None = None,
                                    fuse_strategy: str = "operator",
-                                   batch: int | None = None,
-                                   overlap: bool = True) -> DistributedStepper:
+                                   batch: int | None = None
+                                   ) -> DistributedStepper:
     """Build the fused multi-device sweep: one ``t*r`` exchange per chunk.
 
     ``schedule`` is the static list of chunk depths (e.g. ``[4, 4, 2]`` for
@@ -377,22 +354,17 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
     base = StencilEngine(spec, option=option, backend=backend, block=block,
                          boundary="valid")
     depth_max = max(schedule) if schedule else 1
-    # per depth, the chunk's core over the haloed block and the interior
-    # recompute's core: the same arithmetic, the kernel named apart
+    # per depth, the chunk's core over the haloed block
     cores: dict[int, Callable] = {1: base._core}
-    interiors: dict[int, Callable] = {1: base.named_core(INTERIOR_STEP)}
     for t in sorted(set(schedule)):
         if t > 1:
             if fuse_strategy == "inkernel":
                 cores[t] = base.inkernel_core(t)
-                interiors[t] = base.inkernel_core(t, name=INTERIOR_SWEEP)
                 continue
             opt = fused_option if t == depth_max else "auto"
-            fused = StencilEngine(temporal.fuse_steps(spec, t), option=opt,
-                                  backend=backend, block=base.plan.block,
-                                  boundary="valid")
-            cores[t] = fused._core
-            interiors[t] = fused.named_core(INTERIOR_STEP)
+            cores[t] = StencilEngine(temporal.fuse_steps(spec, t), option=opt,
+                                     backend=backend, block=base.plan.block,
+                                     boundary="valid")._core
 
     grid_axes = tuple(grid_axes)
     lead = 0 if batch is None else 1
@@ -405,8 +377,7 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
             b = distributed_fused_chunk(b, t=t, base_core=cores[1],
                                         fused_core=cores[t], spec=spec,
                                         mesh_axes=mesh_axes,
-                                        periodic=periodic, overlap=overlap,
-                                        interior_core=interiors[t])
+                                        periodic=periodic)
         return b
 
     sharded = jax.shard_map(local_fn, mesh=mesh, in_specs=pspec,
@@ -421,8 +392,8 @@ def make_fused_distributed_stepper(spec: StencilSpec, mesh: Mesh,
 def make_distributed_stepper(spec: StencilSpec, mesh: Mesh,
                              grid_axes: tuple[str, ...],
                              option: str = "auto", backend: str = "jnp",
-                             periodic: bool = True, overlap: bool = True,
-                             steps: int = 1) -> Callable[[jnp.ndarray], jnp.ndarray]:
+                             periodic: bool = True, steps: int = 1
+                             ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     """Build a jit-ted multi-device stencil stepper (width-r exchange/step).
 
     ``grid_axes``: mesh axis name for each spatial array axis (use '' to
@@ -437,7 +408,7 @@ def make_distributed_stepper(spec: StencilSpec, mesh: Mesh,
 
     def local_step(block):
         return distributed_stencil_step(block, engine=engine, mesh_axes=mesh_axes,
-                                        periodic=periodic, overlap=overlap)
+                                        periodic=periodic)
 
     def global_step(x):
         return lax.fori_loop(0, steps, lambda _, a: sharded(a), x) if steps > 1 else sharded(x)

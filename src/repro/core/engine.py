@@ -157,10 +157,8 @@ def register_backend(name: str, builder: Callable, *,
     """Register a stencil execution backend.
 
     ``builder(plan, **opts) -> core`` must return a valid-mode update
-    (shrinks each spatial axis by ``2 * plan.spec.order``); ``opts`` may
-    carry ``name``, the name a kernel-backed core gives its kernel (the
-    others ignore it).  Registration is
-    the extension point third-party kernels use — the engine and the
+    (shrinks each spatial axis by ``2 * plan.spec.order``).  Registration
+    is the extension point third-party kernels use — the engine and the
     planner both dispatch through this table, so a registered backend is
     automatically enumerated, priced (``mxu_efficiency`` modelled fraction
     of peak, optionally refined by a measured calibration record through
@@ -174,7 +172,8 @@ def register_backend(name: str, builder: Callable, *,
     axis by ``2*steps*order``); registering one makes the backend eligible
     for the planner's ``fuse_strategy="inkernel"`` candidates.  ``opts``
     carries the VMEM ``scratch`` policy
-    (``temporal.SCRATCH_MODES``) and may carry ``name`` as above — accept
+    (``temporal.SCRATCH_MODES``) and may carry ``name``, the name a
+    kernel-backed core gives its kernel (the others ignore it) — accept
     ``**opts`` so new options stay backward-compatible.
 
     Raises ``ValueError`` on duplicate names unless ``overwrite=True``.
@@ -280,10 +279,9 @@ class StencilEngine:
         # built core beyond the engine's own frozen plan — fused_engine
         # keys the depth (the cover option is compatibility-checked and
         # rebuilt on mismatch), inkernel_core keys (depth, scratch policy,
-        # kernel name), named_core keys the kernel name.
+        # kernel name).
         # A new knob must join the key, never alias an existing entry.
         self._fused_engines: dict[int, "StencilEngine"] = {}
-        self._named_cores: dict[str, Callable] = {}
         self._inkernel_cores: dict[tuple[int, str, str | None],
                                    Callable] = {}
 
@@ -295,25 +293,14 @@ class StencilEngine:
                    boundary=eplan.problem["boundary"])
 
     # -- construction -------------------------------------------------------
-    def _build_core(self, **opts) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    def _build_core(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
         """The valid-mode update via the backend registry; boundary handling
         is layered on by :func:`repro.core.halo.wrap_boundary`."""
         backend = get_backend(self.plan.backend)
         if not backend.supports(self.plan.spec):
             raise ValueError(f"backend {backend.name!r} does not support "
                              f"{self.plan.spec.describe()}")
-        return backend.builder(self.plan, **opts)
-
-    def named_core(self, name: str) -> Callable[[jnp.ndarray], jnp.ndarray]:
-        """The valid-mode update again, its kernel named ``name`` (cached).
-
-        The same arithmetic as ``_core``; a kernel-backed core compiles
-        the same kernel under another name, so a profiler trace tells a
-        second use of it apart (a backend with no kernel ignores it)."""
-        core = self._named_cores.get(name)
-        if core is None:
-            core = self._named_cores[name] = self._build_core(name=name)
-        return core
+        return backend.builder(self.plan)
 
     # -- execution -----------------------------------------------------------
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:

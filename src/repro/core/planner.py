@@ -1128,13 +1128,12 @@ def _check_plan_input(x, grid: tuple[int, ...], nd: int, batch: int,
                          f"{lead} (plan with batch={lead[0]} to batch)")
 
 
-def compile_plan(eplan: ExecutionPlan, mesh=None, *,
-                 overlap: bool = True) -> CompiledStencil:
+def compile_plan(eplan: ExecutionPlan, mesh=None) -> CompiledStencil:
     """Materialize an ExecutionPlan into an executable.
 
     Distributed plans (``sharding`` set) compile to the fused sharded
-    stepper: ONE ``T*r``-deep halo exchange per fused chunk, interior
-    overlapped with the wire time.  ``mesh`` defaults to rebuilding the
+    stepper: ONE ``T*r``-deep halo exchange per fused chunk, then one
+    sweep of the haloed block.  ``mesh`` defaults to rebuilding the
     recorded mesh shape from the available devices.
     """
     spec = eplan.spec
@@ -1160,8 +1159,7 @@ def compile_plan(eplan: ExecutionPlan, mesh=None, *,
             fused_option=eplan.option if eplan.fuse_depth > 1 else "auto",
             backend=eplan.backend, boundary=boundary, block=eplan.block,
             fuse_strategy=eplan.fuse_strategy,
-            batch=batch if batch > 1 else None,
-            overlap=overlap)
+            batch=batch if batch > 1 else None)
 
         def _checked(inner):
             # same clear shape errors the single-device fn raises, instead

@@ -53,9 +53,7 @@ Each ``pallas_call`` carries a stable ``name`` (``stencil_step``,
 ``stencil_sweep``) with no shape or depth in it: the compiled custom
 call takes that name, so a profiler trace tells the two kernels apart.
 A caller may rename a call (``name=``) where the same kernel does a
-different job: the distributed stepper's interior recompute runs as
-``stencil_sweep_interior`` / ``stencil_step_interior``
-(``core/distributed.py``).
+different job.
 """
 from __future__ import annotations
 

@@ -1,7 +1,6 @@
 """The paper's 2-D order-2 star on a (2,2) mesh, as the ``star2d_r2_49k``
 deployment runs it (49152² over four chips), cut to 1024² on four CPU
-devices: 512² per shard, where the planner picks the full size's plan and
-the overlap's interior branch is taken.
+devices: 512² per shard, where the planner picks the full size's plan.
 
 One child process plans and compiles the problem through ``api.plan`` ->
 ``api.compile``, runs one 16-step call and reports what the tests below
@@ -91,9 +90,9 @@ def test_agrees_with_the_plain_reference(mesh_run):
 
 def test_chunk_and_interior_kernels_are_named_apart(mesh_run):
     """Four chunks of depth 4: each runs the sweep over the haloed block
-    and again over the interior, under a name of its own."""
+    once, and no interior recompute runs beside it."""
     assert mesh_run["kernels"] == {"stencil_sweep": 4,
-                                   "stencil_sweep_interior": 4}
+                                   "stencil_sweep_interior": 0}
 
 
 def test_one_exchange_per_chunk(mesh_run):
@@ -108,9 +107,9 @@ def test_exchange_left_as_zeros_fails_the_comparison(mesh_run):
 
 
 def test_operator_strategy_interior_kernel_is_named_apart():
-    """The operator-fused strategy's interior recompute on the Pallas
-    backend runs as ``stencil_step_interior`` too (a one-device mesh is
-    enough for the jaxpr)."""
+    """The operator-fused strategy on the Pallas backend runs one
+    ``stencil_step`` a chunk and no interior recompute (a one-device mesh
+    is enough for the jaxpr)."""
     import re
 
     import jax
@@ -127,5 +126,4 @@ def test_operator_strategy_interior_kernel_is_named_apart():
     jaxpr = str(jax.make_jaxpr(run.global_fn)(
         jax.ShapeDtypeStruct((64, 64), jnp.float32)))
     names = re.findall(r"name=(stencil\w+)", jaxpr)
-    assert sorted(names) == ["stencil_step"] * 2 + [
-        "stencil_step_interior"] * 2, names
+    assert names == ["stencil_step"] * 2, names

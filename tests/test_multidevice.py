@@ -33,12 +33,11 @@ def test_distributed_stencil_matches_single_device():
         spec = ss.box(2, 1, seed=5)
         x = jnp.asarray(np.random.default_rng(3).normal(size=(64, 32)), jnp.float32)
         for periodic in (True, False):
-            for overlap in (True, False):
-                step = make_distributed_stepper(spec, mesh, ("gx", "gy"),
-                                                periodic=periodic, overlap=overlap)
-                eng = StencilEngine(spec, boundary="periodic" if periodic else "zero")
-                err = float(jnp.abs(step(x) - eng(x)).max())
-                assert err < 1e-5, (periodic, overlap, err)
+            step = make_distributed_stepper(spec, mesh, ("gx", "gy"),
+                                            periodic=periodic)
+            eng = StencilEngine(spec, boundary="periodic" if periodic else "zero")
+            err = float(jnp.abs(step(x) - eng(x)).max())
+            assert err < 1e-5, (periodic, err)
         step5 = make_distributed_stepper(spec, mesh, ("gx", "gy"), steps=5)
         eng = StencilEngine(spec, boundary="periodic")
         ref = x
@@ -301,9 +300,8 @@ def test_fused_distributed_inkernel_one_exchange_per_chunk():
 
 
 def test_distributed_stepper_unsharded_axis_regression():
-    """One sharded + one unsharded spatial axis: the overlap splice used to
-    shape-error (the interior shrank the unsharded axis but the splice index
-    kept slice(None)); the unsharded axis now gets its boundary locally."""
+    """One sharded + one unsharded spatial axis: the unsharded axis gets
+    its boundary locally."""
     run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import stencil_spec as ss
@@ -315,14 +313,12 @@ def test_distributed_stepper_unsharded_axis_regression():
         x = jnp.asarray(np.random.default_rng(3).normal(size=(32, 24)),
                         jnp.float32)
         for periodic in (True, False):
-            for overlap in (True, False):
-                step = make_distributed_stepper(spec, mesh, ("gx", ""),
-                                                periodic=periodic,
-                                                overlap=overlap)
-                eng = StencilEngine(
-                    spec, boundary="periodic" if periodic else "zero")
-                err = float(jnp.abs(step(x) - eng(x)).max())
-                assert err < 1e-5, (periodic, overlap, err)
+            step = make_distributed_stepper(spec, mesh, ("gx", ""),
+                                            periodic=periodic)
+            eng = StencilEngine(
+                spec, boundary="periodic" if periodic else "zero")
+            err = float(jnp.abs(step(x) - eng(x)).max())
+            assert err < 1e-5, (periodic, err)
         print("UNSHARDED AXIS OK")
     """)
 
